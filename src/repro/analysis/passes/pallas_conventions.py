@@ -31,7 +31,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 from repro.analysis.framework import (AnalysisPass, Finding, SourceFile,
                                       register)
 
-_NON_KERNEL_FILES = {"__init__.py", "ops.py", "ref.py", "compat.py"}
+_NON_KERNEL_FILES = {"__init__.py", "ops.py", "ref.py"}
 _MUTABLE_CTORS = {"list", "dict", "set", "bytearray", "deque", "defaultdict"}
 
 

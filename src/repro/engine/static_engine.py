@@ -78,9 +78,20 @@ class StaticEngine:
                  extra_inputs: Optional[Dict[str, np.ndarray]] = None,
                  kv_layout: str = "dense", page_tokens: int = 16,
                  kv_pool_tokens: Optional[int] = None,
-                 prefix_sharing: bool = True, attn_impl: str = "unfused"):
+                 prefix_sharing: bool = True, attn_impl: str = "unfused",
+                 device: Optional[jax.Device] = None):
         self.model = model
-        self.params = params
+        # the device this engine runs on: params, page pool and every input
+        # are placed there (None = JAX's default device), so one process
+        # drives one engine per chip
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
+        # donated pool buffers are updated in place; the CPU ignores
+        # donation and warns, so only donate on accelerators
+        platform = (device.platform if device is not None
+                    else jax.default_backend())
+        self._donate = platform != "cpu"
         self.eos_id = eos_id
         self.pad_id = pad_id
         self.len_bucket = len_bucket
@@ -116,10 +127,18 @@ class StaticEngine:
             self.allocator = PageAllocator(kv_pool_tokens // page_tokens,
                                            page_tokens)
             P = self.allocator.n_pages + 1  # + null page 0
-            shape = (cfg.n_layers, P, page_tokens, cfg.n_kv_heads,
-                     cfg.head_dim)
-            self._k_pages = jnp.zeros(shape, cfg.dtype)
-            self._v_pages = jnp.zeros(shape, cfg.dtype)
+            # a slot's K (and V) is stored lane-dense as one Hkv·D record
+            # (kernels.ref.pool_view): the TPU tiles the last two dims by
+            # (8, 128), so a minor dim of head_dim = 64 fills half a
+            # tile's lanes; Hkv·D (512 for llama3.2-1b) tiles exactly
+            shape = (cfg.n_layers, P, page_tokens,
+                     cfg.n_kv_heads * cfg.head_dim)
+            # filled on the device itself: jnp.zeros(device=...) fills on
+            # the default device and copies, so every engine's pool would
+            # pass through chip 0
+            with jax.default_device(device):
+                self._k_pages = jnp.zeros(shape, cfg.dtype)
+                self._v_pages = jnp.zeros(shape, cfg.dtype)
             self._resident: Dict[int, _Resident] = {}
             self._prefix = PrefixIndex(page_tokens)
             self._stamp = 0
@@ -151,12 +170,11 @@ class StaticEngine:
 
             # donate the pool buffers so XLA updates them in place (the
             # pool is sized to most of HBM; without donation every call
-            # would hold two full copies).  CPU ignores donation and
-            # warns, so only donate on accelerators.
-            donate = (() if jax.default_backend() == "cpu" else (3, 4))
+            # would hold two full copies)
+            donate = (3, 4) if self._donate else ()
             self._prefill_paged = jax.jit(_prefill_paged,
                                           donate_argnums=donate)
-            donate_t = (() if jax.default_backend() == "cpu" else (4, 5))
+            donate_t = (4, 5) if self._donate else ()
             self._prefill_tail_paged = jax.jit(_prefill_tail,
                                                donate_argnums=donate_t)
 
@@ -210,9 +228,8 @@ class StaticEngine:
         from repro.models import transformer as tfm
         cfg, eos = self.model.cfg, self.eos_id
         attn_impl = self.attn_impl
-        # pool buffers donated in place, as in _prefill_paged (CPU ignores
-        # donation and warns, so only donate on accelerators)
-        donate = (() if jax.default_backend() == "cpu" else (1, 2))
+        # pool buffers donated in place, as in _prefill_paged
+        donate = (1, 2) if self._donate else ()
 
         @partial(jax.jit, donate_argnums=donate)
         def serve(params, k_pages, v_pages, block_table, slot_pos, row_len,
@@ -447,8 +464,8 @@ class StaticEngine:
                 if prevs[i]:  # re-prefill beyond the first (§3.3 overhead)
                     reprefill += len(e)
             tok0, self._k_pages, self._v_pages = self._prefill_paged(
-                self.params, jnp.asarray(toks), jnp.asarray(lens),
-                self._k_pages, self._v_pages, jnp.asarray(btp))
+                self.params, self._put(toks), self._put(lens),
+                self._k_pages, self._v_pages, self._put(btp))
             tok0 = np.asarray(tok0)  # host transfer: blocks on stage A
             for s, i in enumerate(pre_idx):
                 first[i] = int(tok0[s])
@@ -477,9 +494,9 @@ class StaticEngine:
                 if prevs[i]:  # only the tail re-runs on a reschedule
                     reprefill += len(e) - st
             tokt, self._k_pages, self._v_pages = self._prefill_tail_paged(
-                self.params, jnp.asarray(toks_t), jnp.asarray(start_t),
-                jnp.asarray(lens_t), self._k_pages, self._v_pages,
-                jnp.asarray(btt))
+                self.params, self._put(toks_t), self._put(start_t),
+                self._put(lens_t), self._k_pages, self._v_pages,
+                self._put(btt))
             tokt = np.asarray(tokt)  # host transfer: blocks on stage A'
             for s, i in enumerate(tail_idx):
                 first[i] = int(tokt[s])
@@ -509,9 +526,9 @@ class StaticEngine:
         forced = self._forced_array(forced_gen_lens, B, B_raw)
         fn = self._get_compiled_paged(slice_len)
         out, steps, done, nxt, kp, vp = fn(
-            self.params, self._k_pages, self._v_pages, jnp.asarray(bt),
-            jnp.asarray(sp), jnp.asarray(np.asarray(lens_full, np.int32)),
-            jnp.asarray(first_full), jnp.asarray(forced))
+            self.params, self._k_pages, self._v_pages, self._put(bt),
+            self._put(sp), self._put(np.asarray(lens_full, np.int32)),
+            self._put(first_full), self._put(forced))
         self._k_pages, self._v_pages = kp, vp
         out = np.asarray(jax.block_until_ready(out))
         nxt = np.asarray(nxt)
@@ -573,12 +590,13 @@ class StaticEngine:
             tokens[i, L - len(e):] = e  # left padding
         lengths_p = np.concatenate([lengths, np.ones(B - B_raw, np.int32)])
         forced = self._forced_array(forced_gen_lens, B, B_raw)
-        extra = {k: self._pad_extra(v, B, B_raw) for k, v in self.extra_inputs.items()}
+        extra = {k: self._put(self._pad_extra(v, B, B_raw))
+                 for k, v in self.extra_inputs.items()}
 
         fn = self._get_compiled(slice_len)
         t0 = time.perf_counter()
-        out, steps, done = fn(self.params, jnp.asarray(tokens),
-                              jnp.asarray(lengths_p), jnp.asarray(forced), extra)
+        out, steps, done = fn(self.params, self._put(tokens),
+                              self._put(lengths_p), self._put(forced), extra)
         out = np.asarray(jax.block_until_ready(out))
         wall = time.perf_counter() - t0
         steps = int(steps)
@@ -624,12 +642,16 @@ class StaticEngine:
                                 pad=pad))
         return results
 
+    def _put(self, x) -> jax.Array:
+        """Host array -> this engine's device."""
+        return jax.device_put(x, self.device)
+
     @staticmethod
-    def _pad_extra(v: np.ndarray, B: int, B_raw: int):
+    def _pad_extra(v: np.ndarray, B: int, B_raw: int) -> np.ndarray:
         if v.shape[0] == B:
-            return jnp.asarray(v)
-        reps = np.concatenate([v, np.repeat(v[-1:], B - B_raw, axis=0)], axis=0)
-        return jnp.asarray(reps)
+            return v
+        return np.concatenate([v, np.repeat(v[-1:], B - B_raw, axis=0)],
+                              axis=0)
 
 
 class ServeResult:

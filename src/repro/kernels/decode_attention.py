@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -95,7 +94,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_pos.astype(jnp.int32), slot_pos.astype(jnp.int32), qg, k_cache, v_cache)

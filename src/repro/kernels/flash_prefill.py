@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -112,7 +111,7 @@ def flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq,), jnp.float32),      # running denom
             pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(positions.astype(jnp.int32), positions.astype(jnp.int32), qt, kt, vt)

@@ -6,8 +6,9 @@ call ``kernels.paged_prefill_write`` to copy it into pages.  This kernel
 folds both into ONE pass: each (row, logical block) grid step loads the
 raw projected K tile, rotates it in-register at its *destination slot*
 positions (compact paged layout: logical slot == absolute position, so
-the rotation angle is derivable from the grid index alone), and DMA's the
-rotated K plus the untouched V straight into their physical pages via
+the rotation is read from a cos/sin table block indexed by the grid
+alone: ``kernels.ref.rope_cos_sin``, the oracle's own values), and DMA's
+the rotated K plus the untouched V straight into their physical pages via
 ``input_output_aliases`` — no rotated-K tensor ever exists in HBM.
 
 Addressing: token destined for logical slot ``s`` of row ``b`` sits at
@@ -32,11 +33,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
+from repro.kernels.fused_rope_decode_append import _rope
+from repro.kernels.ref import rope_cos_sin
 
 
-def _kernel(bt_ref, shift_ref, start_ref, k_ref, v_ref, k_in, v_in,
-            ko_ref, vo_ref, *, pg: int, theta: float, rd_max: int):
+def _kernel(bt_ref, shift_ref, start_ref, k_ref, v_ref, cos_ref, sin_ref,
+            k_in, v_in, ko_ref, vo_ref, *, pg: int, rd_max: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
     base = j * pg  # first logical slot of this block == absolute position
@@ -44,28 +46,16 @@ def _kernel(bt_ref, shift_ref, start_ref, k_ref, v_ref, k_in, v_in,
     # fully-passthrough blocks (below start) may index before the buffer —
     # clamp; their loaded data is discarded by the novel mask below
     rd = jnp.clip(shift_ref[b] + base, 0, rd_max)
-    idx = (slice(None), pl.ds(rd, pg), slice(None), slice(None))
-    k = pl.load(k_ref, idx)[0].astype(jnp.float32)  # (pg, Hkv, D)
-    v = pl.load(v_ref, idx)[0]                      # (pg, Hkv, D)
+    k = k_ref[0, pl.ds(rd, pg)].astype(jnp.float32)  # (pg, Hkv, D)
+    v = v_ref[0, pl.ds(rd, pg)]                      # (pg, Hkv, D)
+    # cos/sin (pg, 1, D/2) at the destination slots (== absolute positions
+    # in the compact paged layout)
+    kr = _rope(k, cos_ref[...], sin_ref[...])
 
-    D = k.shape[-1]
-    half = D // 2
-    slot = base + jax.lax.broadcasted_iota(jnp.int32, (pg, 1), 0)  # (pg, 1)
-    # identical arithmetic to models.common.apply_rope, angle from the
-    # destination slot (== absolute position in the compact paged layout);
-    # iota*2 rebuilds arange(0, D, 2) without capturing a traced constant
-    ar = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1) * 2.0
-    freqs = 1.0 / (theta ** (ar / D))                    # (1, half)
-    ang = slot.astype(jnp.float32) * freqs               # (pg, half)
-    cos = jnp.cos(ang)[:, None, :]                       # (pg, 1, half)
-    sin = jnp.sin(ang)[:, None, :]
-    k1 = k[..., :half]
-    k2 = k[..., half:]
-    kr = jnp.concatenate([k1 * cos - k2 * sin, k2 * cos + k1 * sin], axis=-1)
-
-    novel = (slot >= start_ref[b])[:, :, None]  # (pg, 1, 1)
-    ko_ref[...] = jnp.where(novel, kr.astype(ko_ref.dtype), k_in[0])[None]
-    vo_ref[...] = jnp.where(novel, v, v_in[0])[None]
+    slot = base + jax.lax.broadcasted_iota(jnp.int32, (pg, 1, 1), 0)
+    novel = slot >= start_ref[b]  # (pg, 1, 1)
+    ko_ref[0] = jnp.where(novel, kr.astype(ko_ref.dtype), k_in[0])
+    vo_ref[0] = jnp.where(novel, v, v_in[0])
 
 
 def fused_rope_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
@@ -90,8 +80,11 @@ def fused_rope_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
     vp = jnp.pad(v_new, ((0, 0), (0, overhang), (0, 0), (0, 0)))
     Tp = T + overhang
 
-    kernel = functools.partial(_kernel, pg=pg, theta=float(theta),
-                               rd_max=Tp - pg)
+    # the rotation tables of every logical slot, viewed (nb*pg, 1, D/2) so
+    # a block of pg slots keeps the array's last two dims
+    cos, sin = (t.reshape(nb * pg, 1, D // 2)
+                for t in rope_cos_sin(jnp.arange(nb * pg), D, theta))
+    kernel = functools.partial(_kernel, pg=pg, rd_max=Tp - pg)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # block_table + shift + start
         grid=(B, nb),
@@ -100,6 +93,8 @@ def fused_rope_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
                          lambda b, j, bt, sh, st: (b, 0, 0, 0)),
             pl.BlockSpec((1, Tp, Hkv, D),
                          lambda b, j, bt, sh, st: (b, 0, 0, 0)),
+            pl.BlockSpec((pg, 1, D // 2), lambda b, j, bt, sh, st: (j, 0, 0)),
+            pl.BlockSpec((pg, 1, D // 2), lambda b, j, bt, sh, st: (j, 0, 0)),
             # aliased pool inputs: read for passthrough of non-novel slots
             pl.BlockSpec((1, pg, Hkv, D),
                          lambda b, j, bt, sh, st: (bt[b, j], 0, 0, 0)),
@@ -120,11 +115,11 @@ def fused_rope_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
         # operand indices count the scalar-prefetch args: (bt, shift, start,
-        # k, v, k_pages, v_pages) -> pools are operands 5 and 6
-        input_output_aliases={5: 0, 6: 1},
-        compiler_params=compiler_params(
+        # k, v, cos, sin, k_pages, v_pages) -> pools are operands 7 and 8
+        input_output_aliases={7: 0, 8: 1},
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), shift.astype(jnp.int32),
-      start.astype(jnp.int32), kp, vp, k_pages, v_pages)
+      start.astype(jnp.int32), kp, vp, cos, sin, k_pages, v_pages)
     return out_k, out_v

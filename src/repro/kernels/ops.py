@@ -2,7 +2,9 @@
 
 ``impl`` selects the backend:
   * "xla"       — the pure-jnp reference (default on CPU; also the oracle)
-  * "pallas"    — the TPU kernel (compiled on TPU, interpret-executed on CPU)
+  * "pallas"    — the TPU kernel (compiled on TPU, interpret-executed on
+                  the CPU; any other backend is an error, never a silent
+                  interpret run)
 
 ``set_default_impl`` flips the global default (the engines and models call
 through these wrappers, so one switch moves the whole serving stack onto
@@ -42,8 +44,40 @@ def get_default_impl() -> str:
     return _DEFAULT_IMPL
 
 
+def _layer_pools(k_pages, v_pages, layer, head_dim: int):
+    """The (P,pg,Hkv,D) pools a Pallas kernel takes: ``layer`` of stacked
+    pools in any ``ref.pool_view`` layout, or the pools themselves when
+    ``layer`` is None."""
+    if layer is None:
+        return k_pages, v_pages
+    P, pg = k_pages.shape[1:3]
+    return (k_pages[layer].reshape(P, pg, -1, head_dim),
+            v_pages[layer].reshape(P, pg, -1, head_dim))
+
+
+def _one_layer(fn, layer, k_pages, v_pages, head_dim: int):
+    """Run a page-writing Pallas kernel ``fn(k_pool, v_pool)`` on one layer
+    (``_layer_pools``); returns ``fn``'s outputs with the written pools,
+    put back into the stacked pools, in its last two."""
+    if layer is None:
+        return fn(k_pages, v_pages)
+    *rest, kl, vl = fn(*_layer_pools(k_pages, v_pages, layer, head_dim))
+    return (*rest,
+            k_pages.at[layer].set(kl.reshape(k_pages.shape[1:])),
+            v_pages.at[layer].set(vl.reshape(v_pages.shape[1:])))
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on the TPU, interpreted on the CPU (tests); any other
+    backend raises rather than hiding behind the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels target the TPU (compiled) or the "
+                       f"CPU (interpret mode); backend {backend!r} is "
+                       f"neither")
 
 
 @partial(jax.jit, static_argnames=("window", "impl", "block_q", "block_k"))
@@ -85,24 +119,29 @@ def decode_gqa_attention(q, k_cache, v_cache, slot_pos, q_pos,
 @partial(jax.jit, static_argnames=("window", "impl"))
 def paged_decode_attention(q, k_pages, v_pages, block_table, slot_pos, q_pos,
                            window: Optional[int] = None,
-                           impl: Optional[str] = None):
+                           impl: Optional[str] = None, layer=None):
     """Single-token GQA decode over a paged KV cache. q (B,Hq,D) -> (B,Hq,D).
 
     The page size is the kernel's cache-block size (one grid step per
     page), so no block_w knob: pick ``page_tokens`` TPU-friendly instead.
+    ``layer`` (traced int) selects one layer of stacked pools in any
+    ``ref.pool_view`` layout; None means the pools are a single
+    (P,pg,Hkv,D) layer — the same convention for every paged op below.
     """
     impl = impl or _DEFAULT_IMPL
     if impl == "pallas":
+        k_pages, v_pages = _layer_pools(k_pages, v_pages, layer, q.shape[-1])
         return _paged_decode_pallas(q, k_pages, v_pages, block_table,
                                     slot_pos, q_pos, window=window,
                                     interpret=_interpret())
     return ref.paged_decode_attention_ref(q, k_pages, v_pages, block_table,
-                                          slot_pos, q_pos, window=window)
+                                          slot_pos, q_pos, window=window,
+                                          layer=layer)
 
 
 @partial(jax.jit, static_argnames=("impl",))
 def paged_prefill_write(k_new, v_new, positions, block_table, k_pages,
-                        v_pages, impl: Optional[str] = None):
+                        v_pages, impl: Optional[str] = None, layer=None):
     """Write prefill K/V into the paged pool through block tables.
 
     k/v_new (B,T,Hkv,D) in the repo's left-padded layout; positions (B,T)
@@ -117,16 +156,19 @@ def paged_prefill_write(k_new, v_new, positions, block_table, k_pages,
     impl = impl or _DEFAULT_IMPL
     if impl == "pallas":
         pad = jnp.sum(positions < 0, axis=1).astype(jnp.int32)
-        return _paged_write_pallas(k_new, v_new, pad, block_table,
-                                   k_pages, v_pages, interpret=_interpret())
+        return _one_layer(
+            lambda kp, vp: _paged_write_pallas(k_new, v_new, pad,
+                                               block_table, kp, vp,
+                                               interpret=_interpret()),
+            layer, k_pages, v_pages, k_new.shape[-1])
     return ref.paged_prefill_write_ref(k_new, v_new, positions, block_table,
-                                       k_pages, v_pages)
+                                       k_pages, v_pages, layer=layer)
 
 
 @partial(jax.jit, static_argnames=("theta", "impl"))
 def fused_rope_prefill_write(k_new, v_new, positions, block_table, k_pages,
                              v_pages, theta: float = 10000.0,
-                             impl: Optional[str] = None):
+                             impl: Optional[str] = None, layer=None):
     """Rotate prefill K at its absolute positions AND write K/V into the
     paged pool in one pass.
 
@@ -149,19 +191,22 @@ def fused_rope_prefill_write(k_new, v_new, positions, block_table, k_pages,
             jnp.where(n_real > 0,
                       jnp.max(positions, axis=1).astype(jnp.int32)
                       - n_real + 1, 0), 0)
-        return _fused_write_pallas(k_new, v_new, pad - start, start,
-                                   block_table, k_pages, v_pages,
-                                   theta=theta, interpret=_interpret())
+        return _one_layer(
+            lambda kp, vp: _fused_write_pallas(k_new, v_new, pad - start,
+                                               start, block_table, kp, vp,
+                                               theta=theta,
+                                               interpret=_interpret()),
+            layer, k_pages, v_pages, k_new.shape[-1])
     return ref.fused_rope_prefill_write_ref(k_new, v_new, positions,
                                             block_table, k_pages, v_pages,
-                                            theta=theta)
+                                            theta=theta, layer=layer)
 
 
 @partial(jax.jit, static_argnames=("theta", "window", "impl"))
 def fused_rope_decode_append(q, k_new, v_new, block_table, slot_pos, slots,
                              q_pos, k_pages, v_pages, theta: float = 10000.0,
                              window: Optional[int] = None,
-                             impl: Optional[str] = None):
+                             impl: Optional[str] = None, layer=None):
     """Rotate the new q/k token, append its K/V to its page slot, and run
     paged decode attention — all in one launch.
 
@@ -171,14 +216,17 @@ def fused_rope_decode_append(q, k_new, v_new, block_table, slot_pos, slots,
     (out (B,Hq,D), k_pages, v_pages)."""
     impl = impl or _DEFAULT_IMPL
     if impl == "pallas":
-        return _fused_decode_pallas(q, k_new, v_new, block_table, slot_pos,
-                                    slots, q_pos, k_pages, v_pages,
-                                    theta=theta, window=window,
-                                    interpret=_interpret())
+        return _one_layer(
+            lambda kp, vp: _fused_decode_pallas(q, k_new, v_new, block_table,
+                                                slot_pos, slots, q_pos, kp,
+                                                vp, theta=theta,
+                                                window=window,
+                                                interpret=_interpret()),
+            layer, k_pages, v_pages, q.shape[-1])
     return ref.fused_rope_decode_append_ref(q, k_new, v_new, block_table,
                                             slot_pos, slots, q_pos,
                                             k_pages, v_pages, theta=theta,
-                                            window=window)
+                                            window=window, layer=layer)
 
 
 @partial(jax.jit, static_argnames=("chunk", "impl"))

@@ -7,7 +7,7 @@ This kernel is the write half of that path: the page-gather twin of
 ``kernels.paged_decode_attention`` — one grid step per (row, logical
 block), with the block table and each row's left-pad offset as
 scalar-prefetch operands so the physical destination page is resolved in
-the output BlockSpec index map and each (pg, Hkv·D) tile is DMA'd exactly
+the output BlockSpec index map and each (pg, Hkv, D) page is DMA'd exactly
 once.  The page pools are updated *in place* via ``input_output_aliases``
 (no copy of a pool that is most of HBM).
 
@@ -28,17 +28,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _kernel(bt_ref, pad_ref, k_ref, v_ref, _ko_alias, _vo_alias,
             ko_ref, vo_ref, *, pg: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
     start = pad_ref[b] + j * pg  # row's tokens start after its left pad
-    idx = (slice(None), pl.ds(start, pg), slice(None), slice(None))
-    ko_ref[...] = pl.load(k_ref, idx)
-    vo_ref[...] = pl.load(v_ref, idx)
+    ko_ref[...] = k_ref[:, pl.ds(start, pg)]
+    vo_ref[...] = v_ref[:, pl.ds(start, pg)]
 
 
 def paged_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
@@ -90,7 +87,7 @@ def paged_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
         # operand indices count the scalar-prefetch args: (bt, pad, k, v,
         # k_pages, v_pages) -> pools are operands 4 and 5
         input_output_aliases={4: 0, 5: 1},
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), pad.astype(jnp.int32), kp, vp,

@@ -1,12 +1,26 @@
 """Pure-jnp oracles for the Pallas kernels (the allclose ground truth)."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def pool_view(pages: jnp.ndarray, layer=None) -> tuple:
+    """(index prefix, page_tokens, slot record shape) of a page pool.
+
+    ``layer`` None: one layer (P,pg,*rec); else layer ``layer`` of stacked
+    pools (L,P,pg,*rec).  A slot record is (Hkv,D), or (Hkv·D,) for a pool
+    stored lane-dense as the serving engine does (the TPU's (8, 128)
+    tiles leave half their lanes empty over a minor dim of 64).  Folding the
+    layer into the same gather/scatter as the pages keeps a layer-scanned
+    update in place; slicing the layer out first would copy it."""
+    at = () if layer is None else (layer,)
+    return at, pages.shape[len(at) + 1], pages.shape[len(at) + 2:]
 
 
 def flash_prefill_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -38,7 +52,8 @@ def flash_prefill_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def paged_prefill_write_ref(k_new: jnp.ndarray, v_new: jnp.ndarray,
                             dest_slot: jnp.ndarray, block_table: jnp.ndarray,
-                            k_pages: jnp.ndarray, v_pages: jnp.ndarray):
+                            k_pages: jnp.ndarray, v_pages: jnp.ndarray,
+                            layer=None):
     """Scatter prefill K/V into a paged KV pool through block tables.
 
     k/v_new (B,T,Hkv,D); dest_slot (B,T) int32 — the *logical* cache slot
@@ -46,19 +61,34 @@ def paged_prefill_write_ref(k_new: jnp.ndarray, v_new: jnp.ndarray,
     are permanently masked); block_table (B,nb); k/v_pages (P,pg,Hkv,D).
     Token (b,t) is written to page ``block_table[b, dest_slot//pg]`` at
     offset ``dest_slot % pg``.  Returns the updated (k_pages, v_pages) —
-    the paged twin of ``attention_prefill``'s dense cache build.
+    the paged twin of ``attention_prefill``'s dense cache build.  With
+    ``layer``, the pools are stacked (``pool_view``) and only that layer is
+    written.
     """
     B, T, Hkv, D = k_new.shape
-    pg = k_pages.shape[1]
+    at, pg, rec = pool_view(k_pages, layer)
     nb = block_table.shape[1]
     valid = dest_slot >= 0
     slot = jnp.clip(dest_slot, 0, nb * pg - 1)
     page = jnp.take_along_axis(block_table, slot // pg, axis=1)
     page = jnp.where(valid, page, 0).reshape(-1)   # pads -> null page
     off = jnp.where(valid, slot % pg, 0).reshape(-1)
-    k_pages = k_pages.at[page, off].set(k_new.reshape(B * T, Hkv, D))
-    v_pages = v_pages.at[page, off].set(v_new.reshape(B * T, Hkv, D))
+    at = at + (page, off)
+    k_pages = k_pages.at[at].set(k_new.reshape((B * T,) + rec))
+    v_pages = v_pages.at[at].set(v_new.reshape((B * T,) + rec))
     return k_pages, v_pages
+
+
+def rope_cos_sin(positions: jnp.ndarray, head_dim: int,
+                 theta: float) -> tuple:
+    """(cos, sin) of the RoPE angles at ``positions``, each
+    positions.shape + (head_dim/2,) float32.  The fused Pallas kernels
+    take these tables rather than evaluating the transcendentals
+    in-kernel, so their rotation uses the very values of this oracle."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    ang = positions[..., None].astype(jnp.float32) * freqs
+    return jnp.cos(ang), jnp.sin(ang)
 
 
 def _rope_ref(x: jnp.ndarray, positions: jnp.ndarray,
@@ -66,11 +96,8 @@ def _rope_ref(x: jnp.ndarray, positions: jnp.ndarray,
     """Llama half-rotation RoPE — arithmetic twin of
     ``models.common.apply_rope`` kept local so the oracle module stays
     free of model-package imports.  x (..., T, H, D); positions (..., T)."""
-    D = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    ang = positions[..., None].astype(jnp.float32) * freqs
-    cos = jnp.cos(ang)[..., None, :]
-    sin = jnp.sin(ang)[..., None, :]
+    cos, sin = rope_cos_sin(positions, x.shape[-1], theta)
+    cos, sin = cos[..., None, :], sin[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -80,7 +107,7 @@ def fused_rope_prefill_write_ref(k_new: jnp.ndarray, v_new: jnp.ndarray,
                                  positions: jnp.ndarray,
                                  block_table: jnp.ndarray,
                                  k_pages: jnp.ndarray, v_pages: jnp.ndarray,
-                                 theta: float = 10000.0):
+                                 theta: float = 10000.0, layer=None):
     """Rotate prefill K at its absolute positions, then scatter K/V into
     the paged pool — the one-pass fused kernel's ground truth.
 
@@ -90,7 +117,7 @@ def fused_rope_prefill_write_ref(k_new: jnp.ndarray, v_new: jnp.ndarray,
     (k_pages, v_pages); V is written unrotated."""
     kr = _rope_ref(k_new, jnp.maximum(positions, 0), theta)
     return paged_prefill_write_ref(kr, v_new, positions, block_table,
-                                   k_pages, v_pages)
+                                   k_pages, v_pages, layer=layer)
 
 
 def fused_rope_decode_append_ref(q: jnp.ndarray, k_new: jnp.ndarray,
@@ -99,7 +126,7 @@ def fused_rope_decode_append_ref(q: jnp.ndarray, k_new: jnp.ndarray,
                                  q_pos: jnp.ndarray, k_pages: jnp.ndarray,
                                  v_pages: jnp.ndarray, theta: float = 10000.0,
                                  window: Optional[int] = None,
-                                 scale: Optional[float] = None):
+                                 scale: Optional[float] = None, layer=None):
     """Rotate the new q/k token at ``q_pos``, append its K/V to page slot
     ``slots``, then run paged decode attention over the post-append pool —
     the fused decode kernel's ground truth.
@@ -110,15 +137,16 @@ def fused_rope_decode_append_ref(q: jnp.ndarray, k_new: jnp.ndarray,
     (out (B,Hq,D), k_pages, v_pages)."""
     qr = _rope_ref(q[:, None], q_pos[:, None], theta)[:, 0]
     knr = _rope_ref(k_new[:, None], q_pos[:, None], theta)[:, 0]
-    pg = k_pages.shape[1]
+    at, pg, rec = pool_view(k_pages, layer)
     page = jnp.take_along_axis(block_table, (slots // pg)[:, None],
                                axis=1)[:, 0]
-    off = slots % pg
-    k_pages = k_pages.at[page, off].set(knr.astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v_new.astype(v_pages.dtype))
+    at = at + (page, slots % pg)
+    B = q.shape[0]
+    k_pages = k_pages.at[at].set(knr.astype(k_pages.dtype).reshape((B,) + rec))
+    v_pages = v_pages.at[at].set(v_new.astype(v_pages.dtype).reshape((B,) + rec))
     out = paged_decode_attention_ref(qr, k_pages, v_pages, block_table,
                                      slot_pos, q_pos, window=window,
-                                     scale=scale)
+                                     scale=scale, layer=layer)
     return out, k_pages, v_pages
 
 
@@ -126,20 +154,31 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                                v_pages: jnp.ndarray, block_table: jnp.ndarray,
                                slot_pos: jnp.ndarray, q_pos: jnp.ndarray,
                                window: Optional[int] = None,
-                               scale: Optional[float] = None) -> jnp.ndarray:
+                               scale: Optional[float] = None,
+                               layer=None) -> jnp.ndarray:
     """Single-token GQA decode over a *paged* KV cache.
 
-    q (B,Hq,D); k/v_pages (P,pg,Hkv,D); block_table (B,nb) physical page per
+    q (B,Hq,D); k/v_pages (P,pg,Hkv,D), or stacked pools read at
+    ``layer`` (``pool_view``); block_table (B,nb) physical page per
     logical block; slot_pos (B,nb·pg) (-1 empty); q_pos (B,).
     Materializes the per-row gather the Pallas kernel streams page by page.
     """
-    B = q.shape[0]
-    pg, Hkv, D = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
-    nb = block_table.shape[1]
-    k_cache = k_pages[block_table].reshape(B, nb * pg, Hkv, D)
-    v_cache = v_pages[block_table].reshape(B, nb * pg, Hkv, v_pages.shape[-1])
+    B, _, D = q.shape
+    k_cache, v_cache = gather_pages(k_pages, v_pages, block_table, D, layer)
     return decode_attention_ref(q, k_cache, v_cache, slot_pos, q_pos,
                                 window=window, scale=scale)
+
+
+def gather_pages(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
+                 block_table: jnp.ndarray, head_dim: int, layer=None):
+    """Each row's logical window (B, nb·pg, Hkv, D) read through its block
+    table (``pool_view`` layouts), in one gather per pool."""
+    at, pg, rec = pool_view(k_pages, layer)
+    B, nb = block_table.shape
+    Hkv = math.prod(rec) // head_dim
+    at = at + (block_table,)
+    return (k_pages[at].reshape(B, nb * pg, Hkv, head_dim),
+            v_pages[at].reshape(B, nb * pg, Hkv, -1))
 
 
 def decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,

@@ -3,9 +3,10 @@
 Layout (cf. vLLM's PagedAttention, adapted to the repo's slot_pos
 convention):
 
-  * ``k_pages``/``v_pages`` — (L, P, pg, Hkv, D): P physical pages of
-    ``pg`` token slots each, shared by all batch rows (page 0 is the null
-    page, see ``kvcache.allocator``);
+  * ``k_pages``/``v_pages`` — (L, P, pg, Hkv, D), or (L, P, pg, Hkv·D) as
+    ``StaticEngine`` stores them lane-dense (``kernels.ref.pool_view``):
+    P physical pages of ``pg`` token slots each, shared by all batch rows
+    (page 0 is the null page, see ``kvcache.allocator``);
   * ``block_table`` — (B, nb) int32: logical block j of row b lives in
     physical page ``block_table[b, j]`` (0 = unused → null page);
   * ``slot_pos`` — (B, nb·pg) int32: absolute position stored in each
@@ -28,8 +29,8 @@ import numpy as np
 class PagedKVCache(NamedTuple):
     """Per-model paged KV cache; k/v carry a leading layer axis."""
 
-    k_pages: jnp.ndarray     # (L, P, pg, Hkv, D)
-    v_pages: jnp.ndarray     # (L, P, pg, Hkv, D)
+    k_pages: jnp.ndarray     # (L, P, pg, Hkv, D) or (L, P, pg, Hkv·D)
+    v_pages: jnp.ndarray     # (L, P, pg, Hkv, D) or (L, P, pg, Hkv·D)
     block_table: jnp.ndarray  # (B, nb) int32 physical page per logical block
     slot_pos: jnp.ndarray    # (B, nb·pg) int32 absolute position, -1 empty
     lengths: jnp.ndarray     # (B,) int32 real (unpadded) input lengths

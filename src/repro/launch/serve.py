@@ -1,8 +1,10 @@
 """Serving launcher: run the full SCLS stack through the online
 ``repro.serving`` API (SliceServer over one SchedulerCore).
 
-  # real JAX engines (default): every token really computed
-  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --reduced \
+  # real JAX engines (default): every token really computed, on the toy
+  # preset (--no-reduced serves the published widths; --m-available sizes
+  # each worker's KV budget in bytes)
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
       --workers 2 --rate 2 --duration 15 --strategy scls
 
   # discrete-event sim backend (no model, CI smoke): same scheduler code
@@ -33,20 +35,44 @@
 The real backend profiles the engine, fits the Eq. 3/4 estimator, then
 replays a Poisson trace through ``SliceServer`` — plus one *interactive*
 request submitted mid-run, streamed per slice, to exercise the online
-path (submit → tokens → result) a real deployment uses.  On a real TPU
-cluster each worker becomes a mesh slice and the engine's jit functions
-land on devices unchanged.
+path (submit → tokens → result) a real deployment uses.  Worker ``w``
+runs on device ``w mod len(jax.devices())``: on a four-chip host four
+workers get one chip each, all driven from this one process.
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
 import json
+import os
+import pathlib
 import sys
 
 from repro.cluster.trace import WorkloadSpec, generate_trace
 from repro.configs import ARCHS, get_config
 from repro.serving import ServingConfig, SliceServer, default_sim_environment
+
+
+#: the repository root (this file is src/repro/launch/serve.py)
+_REPO = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    other directory is set.  Otherwise the cache lives at
+    ``<repo>/.jax_cache`` (gitignored).  The path is fixed, never built
+    from a temporary name, a process id or the time: a directory that
+    moves between runs never hits."""
+    import jax  # deferred: the sim path must not require a working model
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_REPO / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def build_server(cfg: ServingConfig) -> tuple[SliceServer, int]:
@@ -65,6 +91,9 @@ def build_server(cfg: ServingConfig) -> tuple[SliceServer, int]:
     if cfg.arch not in ARCHS:
         raise SystemExit(f"unknown --arch {cfg.arch!r}; choose from "
                          f"{sorted(ARCHS)}")
+    devices = jax.devices()
+    print(f"[serve] platform={devices[0].platform} "
+          f"device_kind={devices[0].device_kind} devices={len(devices)}")
     arch = get_config(cfg.arch, reduced=cfg.reduced)
     if arch.family not in ("dense", "moe", "ssm", "hybrid"):
         raise SystemExit(f"serve launcher drives token-only archs; "
@@ -92,15 +121,17 @@ def build_server(cfg: ServingConfig) -> tuple[SliceServer, int]:
                                 page_tokens=cfg.page_tokens,
                                 kv_pool_tokens=mem.total_blocks
                                 * cfg.page_tokens,
-                                prefix_sharing=cfg.prefix_sharing)
-                   for _ in range(cfg.workers)]
+                                prefix_sharing=cfg.prefix_sharing,
+                                device=devices[w % len(devices)])
+                   for w in range(cfg.workers)]
         if cfg.prefix_sharing:
             print("[serve] COW prefix sharing on: matching prompt "
                   "prefixes join resident pages refcounted "
                   "(--no-prefix-sharing disables)")
     else:
-        engines = [StaticEngine(model, params, eos_id=1, len_bucket=8)
-                   for _ in range(cfg.workers)]
+        engines = [StaticEngine(model, params, eos_id=1, len_bucket=8,
+                                device=devices[w % len(devices)])
+                   for w in range(cfg.workers)]
     return cfg.build_real(engines, est, mem), arch.vocab_size
 
 
@@ -155,6 +186,8 @@ def main() -> None:
           f"workers={cfg.workers}"
           + (f" arch={cfg.arch} (reduced={cfg.reduced})"
              if cfg.backend == "real" else ""))
+    if cfg.backend == "real":
+        print(f"[serve] compile cache: {use_compile_cache()}")
     server, vocab = build_server(cfg)
 
     if cfg.http_port is not None:

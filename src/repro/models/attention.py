@@ -239,7 +239,7 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
                             k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                             block_table: jnp.ndarray,
                             mask: Optional[jnp.ndarray] = None,
-                            impl: str = "unfused",
+                            impl: str = "unfused", layer=None,
                             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Prefill that lands K/V in the paged pool (``repro.kvcache``).
 
@@ -249,7 +249,9 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
     ``kernels.ops.paged_prefill_write`` (pads land in the null page).
     Mirrors ``attention_decode_paged`` so prefill and decode both read
     and write the same persistent page pool.  Returns
-    (out, k_pages, v_pages).
+    (out, k_pages, v_pages).  ``layer`` selects one layer of stacked
+    pools in any ``kernels.ref.pool_view`` layout (see
+    ``kernels.ops.paged_decode_attention``).
 
     ``impl="fused"`` routes K through
     ``kernels.ops.fused_rope_prefill_write`` — RoPE applied in-register
@@ -260,6 +262,7 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
     unfused chunked path.
     """
     from repro.kernels import ops as kernel_ops  # deferred: keep models importable without kernels
+    from repro.kernels import ref as kernel_ref
     assert impl in ("unfused", "fused"), impl
     B, T, _ = x.shape
     q, k, v = _qkv(p, x, cfg)
@@ -272,20 +275,19 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
         # position in the compact layout)
         k_pages, v_pages = kernel_ops.fused_rope_prefill_write(
             k, v, positions, block_table, k_pages, v_pages,
-            theta=cfg.rope_theta)
-        pg, Hkv = k_pages.shape[1], k_pages.shape[2]
-        nb = block_table.shape[1]
-        kw = k_pages[block_table].reshape(B, nb * pg, Hkv, k_pages.shape[-1])
-        vw = v_pages[block_table].reshape(B, nb * pg, Hkv, v_pages.shape[-1])
+            theta=cfg.rope_theta, layer=layer)
+        kw, vw = kernel_ref.gather_pages(k_pages, v_pages, block_table,
+                                         cfg.head_dim, layer)
+        W = kw.shape[1]
         lengths = jnp.sum(positions >= 0, axis=1)
-        slots = jnp.arange(nb * pg, dtype=jnp.int32)[None]
+        slots = jnp.arange(W, dtype=jnp.int32)[None]
         pk = jnp.where(slots < lengths[:, None], slots, -1)[:, None, :]
         pq = positions[:, :, None]
         m = (pk >= 0) & (pk <= pq)
         if window is not None:
             m = m & (pq - pk < window)
         # pad query rows would be fully masked -> attend slot 0 (NaN guard)
-        m = m | ((pq < 0) & (jnp.arange(nb * pg)[None, None, :] == 0))
+        m = m | ((pq < 0) & (jnp.arange(W)[None, None, :] == 0))
         o = gqa_attend(q, kw, vw, m[:, None], scale)
         out = dense_apply(p["wo"], o.reshape(B, T, -1))
         return out, k_pages, v_pages
@@ -298,7 +300,7 @@ def attention_prefill_paged(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
         o = gqa_attend(q, k, v, mask, scale)
     out = dense_apply(p["wo"], o.reshape(B, T, -1))
     k_pages, v_pages = kernel_ops.paged_prefill_write(
-        k, v, positions, block_table, k_pages, v_pages)
+        k, v, positions, block_table, k_pages, v_pages, layer=layer)
     return out, k_pages, v_pages
 
 
@@ -308,7 +310,7 @@ def attention_prefill_tail_paged(p: Params, x: jnp.ndarray,
                                  k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                                  block_table: jnp.ndarray,
                                  slot_pos: jnp.ndarray,
-                                 impl: str = "unfused",
+                                 impl: str = "unfused", layer=None,
                                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Tail prefill over a paged pool whose head KV is already resident.
 
@@ -330,24 +332,22 @@ def attention_prefill_tail_paged(p: Params, x: jnp.ndarray,
     attention below is shared by both impls.
     """
     from repro.kernels import ops as kernel_ops  # deferred: keep models importable without kernels
+    from repro.kernels import ref as kernel_ref
     assert impl in ("unfused", "fused"), impl
     B, T, _ = x.shape
-    pg = k_pages.shape[1]
-    nb = block_table.shape[1]
     q, k, v = _qkv(p, x, cfg)
     rp = jnp.maximum(positions, 0)
     q = apply_rope(q, rp, cfg.rope_theta)
     if impl == "fused":
         k_pages, v_pages = kernel_ops.fused_rope_prefill_write(
             k, v, positions, block_table, k_pages, v_pages,
-            theta=cfg.rope_theta)
+            theta=cfg.rope_theta, layer=layer)
     else:
         k = apply_rope(k, rp, cfg.rope_theta)
         k_pages, v_pages = kernel_ops.paged_prefill_write(
-            k, v, positions, block_table, k_pages, v_pages)
-    Hkv = k_pages.shape[2]
-    kw = k_pages[block_table].reshape(B, nb * pg, Hkv, k_pages.shape[-1])
-    vw = v_pages[block_table].reshape(B, nb * pg, Hkv, v_pages.shape[-1])
+            k, v, positions, block_table, k_pages, v_pages, layer=layer)
+    kw, vw = kernel_ref.gather_pages(k_pages, v_pages, block_table,
+                                     cfg.head_dim, layer)
     pq = positions[:, :, None]  # (B,T,1)
     pk = slot_pos[:, None, :]   # (B,1,S)
     m = (pk >= 0) & (pk <= pq)
@@ -355,7 +355,7 @@ def attention_prefill_tail_paged(p: Params, x: jnp.ndarray,
         m = m & (pq - pk < window)
     # pad query rows would be fully masked -> attend slot 0 to avoid NaN
     # (their output is discarded; slot 0 always holds position 0 here)
-    m = m | ((pq < 0) & (jnp.arange(nb * pg)[None, None, :] == 0))
+    m = m | ((pq < 0) & (jnp.arange(kw.shape[1])[None, None, :] == 0))
     o = gqa_attend(q, kw, vw, m[:, None], cfg.head_dim ** -0.5)
     out = dense_apply(p["wo"], o.reshape(B, T, -1))
     return out, k_pages, v_pages
@@ -410,11 +410,13 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, q_pos: jnp.ndarray,
                            block_table: jnp.ndarray, slot_pos: jnp.ndarray,
                            slots: jnp.ndarray, cfg: ModelConfig,
                            window: Optional[int], impl: str = "unfused",
+                           layer=None,
                            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Decode over a paged KV cache (``repro.kvcache``) with per-row slots.
 
-    x (B,1,d); k/v_pages (P,pg,Hkv,D) shared page pool; block_table (B,nb)
-    physical page per logical block; slot_pos (B,nb·pg) over *logical*
+    x (B,1,d); k/v_pages (P,pg,Hkv,D) shared page pool (or stacked pools,
+    ``kernels.ref.pool_view``, read and written at ``layer``);
+    block_table (B,nb) physical page per logical block; slot_pos (B,nb·pg) over *logical*
     slots (must already include the current token position at ``slots``,
     like the dense drivers); slots (B,) logical write slots.  The write
     scatters one token into page ``block_table[b, slots[b]//pg]``; rows
@@ -431,25 +433,27 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, q_pos: jnp.ndarray,
     the correctness baseline.
     """
     from repro.kernels import ops as kernel_ops  # deferred: keep models importable without kernels
+    from repro.kernels import ref as kernel_ref
     assert impl in ("unfused", "fused"), impl
     B = x.shape[0]
-    pg = k_pages.shape[1]
+    at, pg, rec = kernel_ref.pool_view(k_pages, layer)
     q, k, v = _qkv(p, x, cfg)
     if impl == "fused":
         o, k_pages, v_pages = kernel_ops.fused_rope_decode_append(
             q[:, 0], k[:, 0], v[:, 0], block_table, slot_pos, slots, q_pos,
-            k_pages, v_pages, theta=cfg.rope_theta, window=window)
+            k_pages, v_pages, theta=cfg.rope_theta, window=window,
+            layer=layer)
         out = dense_apply(p["wo"], o.reshape(B, 1, -1))
         return out, k_pages, v_pages
     q = apply_rope(q, q_pos[:, None], cfg.rope_theta)
     k = apply_rope(k, q_pos[:, None], cfg.rope_theta)
     pages = jnp.take_along_axis(block_table, (slots // pg)[:, None], axis=1)[:, 0]
-    offs = slots % pg
-    k_pages = k_pages.at[pages, offs].set(k[:, 0])
-    v_pages = v_pages.at[pages, offs].set(v[:, 0])
+    at = at + (pages, slots % pg)
+    k_pages = k_pages.at[at].set(k[:, 0].reshape((B,) + rec))
+    v_pages = v_pages.at[at].set(v[:, 0].reshape((B,) + rec))
     o = kernel_ops.paged_decode_attention(q[:, 0], k_pages, v_pages,
                                           block_table, slot_pos, q_pos,
-                                          window=window)
+                                          window=window, layer=layer)
     out = dense_apply(p["wo"], o.reshape(B, 1, -1))
     return out, k_pages, v_pages
 

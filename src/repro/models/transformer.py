@@ -146,6 +146,22 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     return logits[:, 0], cache
 
 
+def _scan_paged(body, h: jnp.ndarray, layers: Params, k_pages: jnp.ndarray,
+                v_pages: jnp.ndarray):
+    """lax.scan of ``body(h, layer_params, l, k_pages, v_pages)`` over the
+    layers, carrying the whole stacked page pools: each layer
+    gathers and scatters its pages at index ``l`` of the pools, so the
+    update happens in place (pools threaded through scan xs/ys would be
+    copied whole on every call)."""
+    def step(carry, xs):
+        return body(carry[0], *xs, carry[1], carry[2]), None
+
+    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    (h, k_pages, v_pages), _ = jax.lax.scan(
+        step, (h, k_pages, v_pages), (layers, jnp.arange(n)))
+    return h, k_pages, v_pages
+
+
 def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   lengths: jnp.ndarray, cache: PagedKVCache,
                   window: Optional[int] = None, attn_impl: str = "unfused"
@@ -173,17 +189,17 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     big = T >= attn.CHUNK_THRESHOLD
     mask = None if big else attn.prefill_mask(positions, window)
 
-    def body(carry, layer, kp, vp):
+    def body(carry, layer, li, kp, vp):
         x = rms_norm(carry, layer["ln_attn"], cfg.norm_eps)
         a, kp, vp = attn.attention_prefill_paged(
             layer["attn"], x, positions, cfg, window, kp, vp,
-            cache.block_table, mask=mask, impl=attn_impl)
+            cache.block_table, mask=mask, impl=attn_impl, layer=li)
         h2 = carry + a
         m = mlp_apply(layer["mlp"], rms_norm(h2, layer["ln_mlp"], cfg.norm_eps), cfg.act)
-        return h2 + m, (kp, vp)
+        return h2 + m, kp, vp
 
-    h, (k_all, v_all) = scan_layers(body, h, params["layers"],
-                                    cache.k_pages, cache.v_pages)
+    h, k_all, v_all = _scan_paged(body, h, params["layers"],
+                                  cache.k_pages, cache.v_pages)
     logits = _logits(params, cfg, h[:, -1:, :])
     W = cache.window
     slots = jnp.arange(W, dtype=jnp.int32)[None]
@@ -220,17 +236,17 @@ def prefill_tail_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     slots = jnp.arange(W, dtype=jnp.int32)[None]
     slot_pos = jnp.where(slots < lengths[:, None], slots, -1)
 
-    def body(carry, layer, kp, vp):
+    def body(carry, layer, li, kp, vp):
         x = rms_norm(carry, layer["ln_attn"], cfg.norm_eps)
         a, kp, vp = attn.attention_prefill_tail_paged(
             layer["attn"], x, positions, cfg, window, kp, vp,
-            cache.block_table, slot_pos, impl=attn_impl)
+            cache.block_table, slot_pos, impl=attn_impl, layer=li)
         h2 = carry + a
         m = mlp_apply(layer["mlp"], rms_norm(h2, layer["ln_mlp"], cfg.norm_eps), cfg.act)
-        return h2 + m, (kp, vp)
+        return h2 + m, kp, vp
 
-    h, (k_all, v_all) = scan_layers(body, h, params["layers"],
-                                    cache.k_pages, cache.v_pages)
+    h, k_all, v_all = _scan_paged(body, h, params["layers"],
+                                  cache.k_pages, cache.v_pages)
     logits = _logits(params, cfg, h[:, -1:, :])
     return logits[:, 0], cache._replace(k_pages=k_all, v_pages=v_all,
                                         slot_pos=slot_pos,
@@ -307,17 +323,17 @@ def decode_step_paged(params: Params, cfg: ModelConfig, cache: PagedKVCache,
     slot_pos = cache.slot_pos * (1 - oh) + q_pos[:, None].astype(jnp.int32) * oh
     h = embed_apply(params["embed"], tokens[:, None], cfg)
 
-    def body(carry, layer, kp, vp):
+    def body(carry, layer, li, kp, vp):
         x = rms_norm(carry, layer["ln_attn"], cfg.norm_eps)
         a, kp, vp = attn.attention_decode_paged(
             layer["attn"], x, q_pos, kp, vp, cache.block_table, slot_pos,
-            slots, cfg, window, impl=attn_impl)
+            slots, cfg, window, impl=attn_impl, layer=li)
         h2 = carry + a
         m = mlp_apply(layer["mlp"], rms_norm(h2, layer["ln_mlp"], cfg.norm_eps), cfg.act)
-        return h2 + m, (kp, vp)
+        return h2 + m, kp, vp
 
-    h, (k_all, v_all) = scan_layers(body, h, params["layers"],
-                                    cache.k_pages, cache.v_pages)
+    h, k_all, v_all = _scan_paged(body, h, params["layers"],
+                                  cache.k_pages, cache.v_pages)
     logits = _logits(params, cfg, h)[:, 0]
     return logits, cache._replace(k_pages=k_all, v_pages=v_all,
                                   slot_pos=slot_pos)

@@ -266,7 +266,15 @@ class ServingConfig:
         ap.add_argument("--noise-sigma", type=float, default=cls.noise_sigma)
         ap.add_argument("--seed", type=int, default=cls.seed)
         ap.add_argument("--arch", default=cls.arch)
-        ap.add_argument("--reduced", action="store_true", default=cls.reduced)
+        ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                        default=cls.reduced,
+                        help="real backend: serve the arch's toy preset "
+                             "(default); --no-reduced serves its published "
+                             "widths")
+        ap.add_argument("--m-available", type=float,
+                        default=cls.m_available,
+                        help="real backend: bytes of device memory each "
+                             "worker budgets for KV (Eq. 5-9)")
         ap.add_argument("--rate", type=float, default=cls.rate)
         ap.add_argument("--duration", type=float, default=cls.duration)
         ap.add_argument("--http-port", type=int, default=cls.http_port,

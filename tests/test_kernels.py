@@ -191,6 +191,20 @@ def test_paged_decode_ring_positions():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_only_on_the_cpu(monkeypatch, backend, interpret):
+    """Kernels compile on the TPU and are interpreted on the CPU; any other
+    backend is an error, never a silent interpret run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
 def test_paged_ops_dispatch_xla_equals_pallas():
     B, nb, pg, Hkv, D = 2, 2, 8, 2, 16
     P = B * nb + 1

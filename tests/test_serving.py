@@ -5,6 +5,7 @@ import copy
 import itertools
 import json
 import os
+import pathlib
 
 import numpy as np
 import pytest
@@ -347,6 +348,35 @@ def test_serving_config_from_dict_and_cli_roundtrip():
     assert ServingConfig.from_dict(cli.to_dict()) == cli
     with pytest.raises(SystemExit):  # invalid combo -> argparse error
         ServingConfig.from_cli(["--strategy", "scls", "--predictor", "proxy"])
+
+
+def test_cli_reduced_flag_and_kv_budget():
+    """--no-reduced serves the published widths; the toy preset stays the
+    default; --m-available sets the per-worker KV budget."""
+    assert ServingConfig.from_cli([]).reduced is True
+    assert ServingConfig.from_cli(["--reduced"]).reduced is True
+    cli = ServingConfig.from_cli(["--no-reduced", "--m-available", "4e9"])
+    assert cli.reduced is False and cli.m_available == 4e9
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the cache sits
+    at a fixed <repo>/.jax_cache."""
+    import jax
+
+    from repro.launch.serve import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert use_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert use_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+        assert use_compile_cache() == str(repo / ".jax_cache")  # stable
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_strategy_config_and_memory_builders():
