@@ -49,6 +49,12 @@ class Request:
     pad_tokens: int = 0
     invalid_tokens: int = 0
     output_tokens: Optional[list] = None  # generated token ids (real mode)
+    # host clock (time.perf_counter seconds), stamped by the online real
+    # serving path and read only by observability: the end of the
+    # request's submit, and when it last became ready to dispatch (the
+    # submit, then each slice end).  None on the simulator.
+    host_submit: Optional[float] = None
+    host_ready: Optional[float] = None
 
     @property
     def effective_input_len(self) -> int:

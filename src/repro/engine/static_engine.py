@@ -41,6 +41,7 @@ import numpy as np
 from repro.core.request import bucket_len
 from repro.engine.sampling import greedy
 from repro.models.registry import Model
+from repro.obs.trace import NULL_TRACER, Tracer
 
 
 def _pow2_bucket(n: int) -> int:
@@ -101,6 +102,9 @@ class StaticEngine:
         # "fused" routes the paged path through the fused RoPE+page-write /
         # RoPE+append+attention kernels; "unfused" is the baseline
         self.attn_impl = attn_impl
+        #: program spans of the paged path (repro.obs); the serving
+        #: backend binds its tracer here
+        self.tracer: Tracer = NULL_TRACER
         self._compiled: Dict[Tuple[int, int, int], object] = {}
         self.compile_seconds = 0.0
         if kv_layout not in ("dense", "paged"):
@@ -252,7 +256,8 @@ class StaticEngine:
                 logits, cache = tfm.decode_step_paged(params, cfg, cache,
                                                       cur, q_pos, q_pos,
                                                       attn_impl=attn_impl)
-                nxt = greedy(logits)
+                with jax.named_scope("sampling"):
+                    nxt = greedy(logits)
                 return step + 1, nxt, cache, done, out
 
             out = jnp.full((B, slice_len), -1, jnp.int32)
@@ -366,6 +371,157 @@ class StaticEngine:
         # granted in THIS call are unwound before re-raising — otherwise
         # their ownerless reservations would wedge the pool for those rids
         # (reserve would KeyError on retry, masking the real failure)
+        tr = self.tracer
+        with tr.span("engine.plan", rows=B_raw) as sp:
+            evicted = self.n_evictions
+            is_resident, shared_start, shared_blocks = self._plan(
+                rids, eff, slice_len)
+            sp.set(evictions=self.n_evictions - evicted)
+
+        # --- stage A: paged prefill of the non-resident rows
+        # (clock starts here, just before device work, mirroring
+        # serve_batch — so retain-mode latency comparisons measure the
+        # same quantity and exclude host-side allocator bookkeeping)
+        t0 = time.perf_counter()
+        t_prefill = 0.0  # every row resident -> no stage-A device call
+        first = np.zeros((B_raw,), np.int32)
+        row_len = np.zeros((B_raw,), np.int64)
+        pads = [0] * B_raw
+        reprefill = 0
+        prefix_hit = sum(shared_start.values())
+        pre_idx = [i for i in range(B_raw)
+                   if not is_resident[i] and i not in shared_start]
+        tail_idx = sorted(shared_start)
+        L_pre = 0
+        if pre_idx:
+            max_eff = max(len(eff[i]) for i in pre_idx)
+            L_pre = bucket_len(max_eff, self.len_bucket)
+            Bp = _pow2_bucket(len(pre_idx))
+            with tr.span("engine.prefill", rows=len(pre_idx), padded=Bp,
+                         length=L_pre):
+                toks = np.full((Bp, L_pre), self.pad_id, np.int32)
+                lens = np.ones((Bp,), np.int32)
+                nb_p = -(-L_pre // pg)
+                btp = np.zeros((Bp, nb_p), np.int32)
+                for s, i in enumerate(pre_idx):
+                    e = eff[i]
+                    toks[s, L_pre - len(e):] = e
+                    lens[s] = len(e)
+                    pages = self.allocator.pages_of(rids[i])
+                    btp[s, :min(len(pages), nb_p)] = pages[:nb_p]
+                    if prevs[i]:  # re-prefill beyond the first (§3.3)
+                        reprefill += len(e)
+                tok0, self._k_pages, self._v_pages = self._prefill_paged(
+                    self.params, self._put(toks), self._put(lens),
+                    self._k_pages, self._v_pages, self._put(btp))
+                tok0 = np.asarray(tok0)  # host transfer: blocks on stage A
+                for s, i in enumerate(pre_idx):
+                    first[i] = int(tok0[s])
+                    row_len[i] = len(eff[i])
+                    pads[i] = L_pre - len(eff[i])
+        # --- stage A': tail-only prefill of the prefix-sharing rows — the
+        # shared head is a page-table remap, only the novel tail runs
+        if tail_idx:
+            max_tail = max(len(eff[i]) - shared_start[i] for i in tail_idx)
+            T_t = bucket_len(max_tail, self.len_bucket)
+            Bt = _pow2_bucket(len(tail_idx))
+            with tr.span("engine.prefill_tail", rows=len(tail_idx),
+                         padded=Bt, length=T_t):
+                toks_t = np.full((Bt, T_t), self.pad_id, np.int32)
+                start_t = np.zeros((Bt,), np.int32)
+                lens_t = np.zeros((Bt,), np.int32)
+                nb_t = bucket_len(max(len(self.allocator.pages_of(rids[i]))
+                                      for i in tail_idx), NB_BUCKET)
+                btt = np.zeros((Bt, nb_t), np.int32)
+                for s, i in enumerate(tail_idx):
+                    e, st = eff[i], shared_start[i]
+                    toks_t[s, T_t - (len(e) - st):] = e[st:]
+                    start_t[s] = st
+                    lens_t[s] = len(e)
+                    pages = self.allocator.pages_of(rids[i])
+                    btt[s, :min(len(pages), nb_t)] = pages[:nb_t]
+                    if prevs[i]:  # only the tail re-runs on a reschedule
+                        reprefill += len(e) - st
+                tokt, self._k_pages, self._v_pages = \
+                    self._prefill_tail_paged(
+                        self.params, self._put(toks_t), self._put(start_t),
+                        self._put(lens_t), self._k_pages, self._v_pages,
+                        self._put(btt))
+                tokt = np.asarray(tokt)  # host transfer: blocks on stage A'
+                for s, i in enumerate(tail_idx):
+                    first[i] = int(tokt[s])
+                    row_len[i] = len(eff[i])
+                    pads[i] = T_t - (len(eff[i]) - shared_start[i])
+        if pre_idx or tail_idx:
+            t_prefill = time.perf_counter() - t0
+        for i, rid in enumerate(rids):
+            if is_resident[i]:
+                res = self._resident[rid]
+                first[i] = res.next_token
+                row_len[i] = res.n_tokens
+
+        # --- stage B: one decode slice over the whole batch through the
+        # per-row block tables (remapped retained pages + fresh ones)
+        from repro.kvcache.paged import batch_block_table, batch_slot_pos
+        B = _pow2_bucket(B_raw)
+        with tr.span("engine.decode", padded=B, rows=B_raw) as ph:
+            max_pages = max(len(self.allocator.pages_of(r)) for r in rids)
+            nb = bucket_len(max_pages, NB_BUCKET)
+            pages_rows = [self.allocator.pages_of(r) for r in rids] \
+                + [[] for _ in range(B - B_raw)]
+            bt = batch_block_table(pages_rows, nb)
+            lens_full = row_len.tolist() + [0] * (B - B_raw)
+            slot_pos = batch_slot_pos(lens_full, nb, pg)
+            first_full = np.concatenate(
+                [first, np.full((B - B_raw,), self.pad_id, np.int32)])
+            forced = self._forced_array(forced_gen_lens, B, B_raw)
+            fn = self._get_compiled_paged(slice_len)
+            out, steps, done, nxt, kp, vp = fn(
+                self.params, self._k_pages, self._v_pages, self._put(bt),
+                self._put(slot_pos),
+                self._put(np.asarray(lens_full, np.int32)),
+                self._put(first_full), self._put(forced))
+            self._k_pages, self._v_pages = kp, vp
+            out = np.asarray(jax.block_until_ready(out))
+            nxt = np.asarray(nxt)
+            wall = time.perf_counter() - t0
+            steps = int(steps)
+            ph.set(blocks=nb, steps=steps)
+
+        # --- retention: trim every row to its resident tokens; pages are
+        # freed only via release_request (finish/cancel) or eviction
+        results = self._assemble_results(out, steps, done, forced_gen_lens,
+                                         pads)
+        with tr.span("engine.retain", rows=B_raw):
+            for i, rid in enumerate(rids):
+                new_len = int(row_len[i]) + steps
+                self._stamp += 1
+                self._resident[rid] = _Resident(new_len, int(nxt[i]),
+                                                self._stamp)
+                self.allocator.shrink(rid, new_len)
+                if self.prefix_sharing:
+                    # index the row's full pages for future prefix joins;
+                    # the resident stream is prompt+generated so far
+                    # followed by every token this slice fed the decoder
+                    stream = np.concatenate([eff[i], out[i, :steps]])
+                    self._prefix.insert(rid, stream,
+                                        self.allocator.pages_of(rid))
+        L_rep = bucket_len(int(max(row_len)), self.len_bucket)
+        return ServeResult(results=results, steps=steps, wall_time=wall,
+                           batch_input_len=max(L_pre, L_rep),
+                           batch_size=B_raw,
+                           early_return=steps < slice_len,
+                           reprefill_tokens=reprefill,
+                           prefill_time=t_prefill,
+                           prefix_hit_tokens=prefix_hit,
+                           shared_blocks=shared_blocks)
+
+    def _plan(self, rids: Sequence[int], eff: List[np.ndarray],
+              slice_len: int) -> Tuple[List[bool], Dict[int, int], int]:
+        """Capacity planning of one paged slice: returns each row's
+        residency, the shared-prefix start of each prefix-joining row and
+        the pages those joins took."""
+        pg = self.page_tokens
         batch_set = set(rids)
         is_resident = []
         fresh: List[int] = []               # reserved this call, no residency
@@ -431,136 +587,7 @@ class StaticEngine:
                 if rid in self._resident:  # not evicted meanwhile
                     self.allocator.shrink(rid, n_before)
             raise
-
-        # --- stage A: paged prefill of the non-resident rows
-        # (clock starts here, just before device work, mirroring
-        # serve_batch — so retain-mode latency comparisons measure the
-        # same quantity and exclude host-side allocator bookkeeping)
-        t0 = time.perf_counter()
-        t_prefill = 0.0  # every row resident -> no stage-A device call
-        first = np.zeros((B_raw,), np.int32)
-        row_len = np.zeros((B_raw,), np.int64)
-        pads = [0] * B_raw
-        reprefill = 0
-        prefix_hit = sum(shared_start.values())
-        pre_idx = [i for i in range(B_raw)
-                   if not is_resident[i] and i not in shared_start]
-        tail_idx = sorted(shared_start)
-        L_pre = 0
-        if pre_idx:
-            max_eff = max(len(eff[i]) for i in pre_idx)
-            L_pre = bucket_len(max_eff, self.len_bucket)
-            Bp = _pow2_bucket(len(pre_idx))
-            toks = np.full((Bp, L_pre), self.pad_id, np.int32)
-            lens = np.ones((Bp,), np.int32)
-            nb_p = -(-L_pre // pg)
-            btp = np.zeros((Bp, nb_p), np.int32)
-            for s, i in enumerate(pre_idx):
-                e = eff[i]
-                toks[s, L_pre - len(e):] = e
-                lens[s] = len(e)
-                pages = self.allocator.pages_of(rids[i])
-                btp[s, :min(len(pages), nb_p)] = pages[:nb_p]
-                if prevs[i]:  # re-prefill beyond the first (§3.3 overhead)
-                    reprefill += len(e)
-            tok0, self._k_pages, self._v_pages = self._prefill_paged(
-                self.params, self._put(toks), self._put(lens),
-                self._k_pages, self._v_pages, self._put(btp))
-            tok0 = np.asarray(tok0)  # host transfer: blocks on stage A
-            for s, i in enumerate(pre_idx):
-                first[i] = int(tok0[s])
-                row_len[i] = len(eff[i])
-                pads[i] = L_pre - len(eff[i])
-        # --- stage A': tail-only prefill of the prefix-sharing rows — the
-        # shared head is a page-table remap, only the novel tail runs
-        if tail_idx:
-            max_tail = max(len(eff[i]) - shared_start[i] for i in tail_idx)
-            T_t = bucket_len(max_tail, self.len_bucket)
-            Bt = _pow2_bucket(len(tail_idx))
-            toks_t = np.full((Bt, T_t), self.pad_id, np.int32)
-            start_t = np.zeros((Bt,), np.int32)
-            lens_t = np.zeros((Bt,), np.int32)
-            nb_t = bucket_len(
-                max(len(self.allocator.pages_of(rids[i])) for i in tail_idx),
-                NB_BUCKET)
-            btt = np.zeros((Bt, nb_t), np.int32)
-            for s, i in enumerate(tail_idx):
-                e, st = eff[i], shared_start[i]
-                toks_t[s, T_t - (len(e) - st):] = e[st:]
-                start_t[s] = st
-                lens_t[s] = len(e)
-                pages = self.allocator.pages_of(rids[i])
-                btt[s, :min(len(pages), nb_t)] = pages[:nb_t]
-                if prevs[i]:  # only the tail re-runs on a reschedule
-                    reprefill += len(e) - st
-            tokt, self._k_pages, self._v_pages = self._prefill_tail_paged(
-                self.params, self._put(toks_t), self._put(start_t),
-                self._put(lens_t), self._k_pages, self._v_pages,
-                self._put(btt))
-            tokt = np.asarray(tokt)  # host transfer: blocks on stage A'
-            for s, i in enumerate(tail_idx):
-                first[i] = int(tokt[s])
-                row_len[i] = len(eff[i])
-                pads[i] = T_t - (len(eff[i]) - shared_start[i])
-        if pre_idx or tail_idx:
-            t_prefill = time.perf_counter() - t0
-        for i, rid in enumerate(rids):
-            if is_resident[i]:
-                res = self._resident[rid]
-                first[i] = res.next_token
-                row_len[i] = res.n_tokens
-
-        # --- stage B: one decode slice over the whole batch through the
-        # per-row block tables (remapped retained pages + fresh ones)
-        from repro.kvcache.paged import batch_block_table, batch_slot_pos
-        B = _pow2_bucket(B_raw)
-        max_pages = max(len(self.allocator.pages_of(r)) for r in rids)
-        nb = bucket_len(max_pages, NB_BUCKET)
-        pages_rows = [self.allocator.pages_of(r) for r in rids] \
-            + [[] for _ in range(B - B_raw)]
-        bt = batch_block_table(pages_rows, nb)
-        lens_full = row_len.tolist() + [0] * (B - B_raw)
-        sp = batch_slot_pos(lens_full, nb, pg)
-        first_full = np.concatenate(
-            [first, np.full((B - B_raw,), self.pad_id, np.int32)])
-        forced = self._forced_array(forced_gen_lens, B, B_raw)
-        fn = self._get_compiled_paged(slice_len)
-        out, steps, done, nxt, kp, vp = fn(
-            self.params, self._k_pages, self._v_pages, self._put(bt),
-            self._put(sp), self._put(np.asarray(lens_full, np.int32)),
-            self._put(first_full), self._put(forced))
-        self._k_pages, self._v_pages = kp, vp
-        out = np.asarray(jax.block_until_ready(out))
-        nxt = np.asarray(nxt)
-        wall = time.perf_counter() - t0
-        steps = int(steps)
-
-        # --- retention: trim every row to its resident tokens; pages are
-        # freed only via release_request (finish/cancel) or eviction
-        results = self._assemble_results(out, steps, done, forced_gen_lens,
-                                         pads)
-        for i, rid in enumerate(rids):
-            new_len = int(row_len[i]) + steps
-            self._stamp += 1
-            self._resident[rid] = _Resident(new_len, int(nxt[i]),
-                                            self._stamp)
-            self.allocator.shrink(rid, new_len)
-            if self.prefix_sharing:
-                # index the row's full pages for future prefix joins; the
-                # resident stream is prompt+generated so far followed by
-                # every token this slice fed the decoder (out rows)
-                stream = np.concatenate([eff[i], out[i, :steps]])
-                self._prefix.insert(rid, stream,
-                                    self.allocator.pages_of(rid))
-        L_rep = bucket_len(int(max(row_len)), self.len_bucket)
-        return ServeResult(results=results, steps=steps, wall_time=wall,
-                           batch_input_len=max(L_pre, L_rep),
-                           batch_size=B_raw,
-                           early_return=steps < slice_len,
-                           reprefill_tokens=reprefill,
-                           prefill_time=t_prefill,
-                           prefix_hit_tokens=prefix_hit,
-                           shared_blocks=shared_blocks)
+        return is_resident, shared_start, shared_blocks
 
     # ------------------------------------------------------------------
     def serve_batch(self, prompts: Sequence[np.ndarray], slice_len: int,

@@ -447,13 +447,18 @@ def attention_decode_paged(p: Params, x: jnp.ndarray, q_pos: jnp.ndarray,
         return out, k_pages, v_pages
     q = apply_rope(q, q_pos[:, None], cfg.rope_theta)
     k = apply_rope(k, q_pos[:, None], cfg.rope_theta)
-    pages = jnp.take_along_axis(block_table, (slots // pg)[:, None], axis=1)[:, 0]
-    at = at + (pages, slots % pg)
-    k_pages = k_pages.at[at].set(k[:, 0].reshape((B,) + rec))
-    v_pages = v_pages.at[at].set(v[:, 0].reshape((B,) + rec))
-    o = kernel_ops.paged_decode_attention(q[:, 0], k_pages, v_pages,
-                                          block_table, slot_pos, q_pos,
-                                          window=window, layer=layer)
+    with jax.named_scope("kv_append"):
+        pages = jnp.take_along_axis(block_table, (slots // pg)[:, None],
+                                    axis=1)[:, 0]
+        at = at + (pages, slots % pg)
+        k_pages = k_pages.at[at].set(k[:, 0].reshape((B,) + rec))
+        v_pages = v_pages.at[at].set(v[:, 0].reshape((B,) + rec))
+    # the paged read: page gather and softmax on the jnp path, the
+    # page-streaming kernel on the Pallas path
+    with jax.named_scope("kv_gather"):
+        o = kernel_ops.paged_decode_attention(q[:, 0], k_pages, v_pages,
+                                              block_table, slot_pos, q_pos,
+                                              window=window, layer=layer)
     out = dense_apply(p["wo"], o.reshape(B, 1, -1))
     return out, k_pages, v_pages
 

@@ -323,17 +323,25 @@ def decode_step_paged(params: Params, cfg: ModelConfig, cache: PagedKVCache,
     slot_pos = cache.slot_pos * (1 - oh) + q_pos[:, None].astype(jnp.int32) * oh
     h = embed_apply(params["embed"], tokens[:, None], cfg)
 
+    # named scopes (metadata only) let a device trace sum the step's
+    # time by part: attention (with kv_append / kv_gather inside), mlp,
+    # head; the engine adds sampling
     def body(carry, layer, li, kp, vp):
-        x = rms_norm(carry, layer["ln_attn"], cfg.norm_eps)
-        a, kp, vp = attn.attention_decode_paged(
-            layer["attn"], x, q_pos, kp, vp, cache.block_table, slot_pos,
-            slots, cfg, window, impl=attn_impl, layer=li)
+        with jax.named_scope("attention"):
+            x = rms_norm(carry, layer["ln_attn"], cfg.norm_eps)
+            a, kp, vp = attn.attention_decode_paged(
+                layer["attn"], x, q_pos, kp, vp, cache.block_table,
+                slot_pos, slots, cfg, window, impl=attn_impl, layer=li)
         h2 = carry + a
-        m = mlp_apply(layer["mlp"], rms_norm(h2, layer["ln_mlp"], cfg.norm_eps), cfg.act)
+        with jax.named_scope("mlp"):
+            m = mlp_apply(layer["mlp"],
+                          rms_norm(h2, layer["ln_mlp"], cfg.norm_eps),
+                          cfg.act)
         return h2 + m, kp, vp
 
     h, k_all, v_all = _scan_paged(body, h, params["layers"],
                                   cache.k_pages, cache.v_pages)
-    logits = _logits(params, cfg, h)[:, 0]
+    with jax.named_scope("head"):
+        logits = _logits(params, cfg, h)[:, 0]
     return logits, cache._replace(k_pages=k_all, v_pages=v_all,
                                   slot_pos=slot_pos)

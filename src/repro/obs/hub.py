@@ -22,6 +22,12 @@ Construction:
     always, Chrome tracing when ``trace=True`` (what ``ServingConfig``
     builds for servers).
 
+Clocks: on the sim backend events are stamped in core time; on the real
+backend (``host_clock`` on the backend) the tracer is bound to
+``time.perf_counter`` and the latency histograms observe host seconds.
+Backend compilations are counted and traced through one
+``jax.monitoring`` listener per process (:func:`_on_jax_duration`).
+
 Metric catalog (all ``scls_`` namespaced; catalog with units in
 ``docs/observability.md``):
 
@@ -31,7 +37,7 @@ Metric catalog (all ``scls_`` namespaced; catalog with units in
               ``scls_requests_total{outcome}``,
               ``scls_admission_total{action,reason}``,
               ``scls_reprefill_tokens_total``,
-              ``scls_prefix_hit_tokens_total``
+              ``scls_prefix_hit_tokens_total``, ``scls_compiles_total``
   gauges      ``scls_queue_depth``, ``scls_in_flight_slices``,
               ``scls_kv_free_pages``, ``scls_kv_retained_blocks``,
               ``scls_kv_evictions``, ``scls_kv_shared_blocks``
@@ -39,20 +45,52 @@ Metric catalog (all ``scls_`` namespaced; catalog with units in
 from __future__ import annotations
 
 import json
+import time
+import weakref
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 from repro.obs.audit import DecisionLog
 from repro.obs.metrics import (DEFAULT_TIME_BUCKETS, DEFAULT_TOKEN_BUCKETS,
                                MetricsRegistry)
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NULL_TRACER, Tracer, innermost_span
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.core.request import Batch, Request
     from repro.serving.admission import AdmissionDecision
+    from repro.serving.backends import BatchExecution
     from repro.serving.core import SchedulerCore
 
 __all__ = ["Observability", "ServingInstruments", "OBS_OFF",
            "decisions_path_for"]
+
+#: the ``jax.monitoring`` event of one backend (XLA) compilation
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+#: bundles attached to a host-clock backend: each one counts (and, when
+#: tracing, traces) the process's compilations
+_HOST_BUNDLES: "weakref.WeakSet[Observability]" = weakref.WeakSet()
+_listening = False
+
+
+def _on_jax_duration(event: str, secs: float, **_: object) -> None:
+    if event != BACKEND_COMPILE:
+        return
+    end = time.perf_counter()
+    inner = innermost_span()
+    for obs in list(_HOST_BUNDLES):
+        if obs.enabled:
+            obs.on_compile(end, float(secs),
+                           inner.name if inner is not None else None)
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_on_jax_duration` once per process."""
+    global _listening
+    if not _listening:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _listening = True
 
 
 def decisions_path_for(trace_path: str) -> str:
@@ -70,15 +108,19 @@ class ServingInstruments:
         self.registry = registry
         self.ttft = registry.histogram(
             "scls_ttft_seconds",
-            "Time to first token in core seconds (slice-granular)",
+            "Time to first token (slice-granular): host seconds from "
+            "submit to the end of the first token's slice on the real "
+            "backend, core seconds on the simulator",
             buckets=DEFAULT_TIME_BUCKETS)
         self.response = registry.histogram(
             "scls_response_seconds",
-            "End-to-end response latency in core seconds",
+            "End-to-end response latency: host seconds from submit to "
+            "finalize on the real backend, core seconds on the simulator",
             buckets=DEFAULT_TIME_BUCKETS)
         self.slice_time = registry.histogram(
             "scls_slice_seconds",
-            "Execution time of one dispatched slice in core seconds",
+            "Time of one dispatched slice: host seconds of its run on the "
+            "real backend, core seconds on the simulator",
             buckets=DEFAULT_TIME_BUCKETS)
         self.reprefill_hist = registry.histogram(
             "scls_slice_reprefill_tokens",
@@ -102,6 +144,10 @@ class ServingInstruments:
             "scls_prefix_hit_tokens_total",
             "Prompt tokens satisfied by a shared-prefix page join "
             "instead of prefill (COW paged KV)")
+        self.compiles = registry.counter(
+            "scls_compiles_total",
+            "Backend (XLA) compilations in this process while serving on "
+            "the real backend")
         self.queue_depth = registry.gauge(
             "scls_queue_depth",
             "Requests waiting to be dispatched (pool + worker queues)")
@@ -134,6 +180,8 @@ class Observability:
         self.ins: Optional[ServingInstruments] = (
             ServingInstruments(registry) if registry is not None else None)
         self.audit = audit
+        #: bound to a host-clock backend (set by :meth:`attach`)
+        self.host_clock = False
         #: the single guard scheduler hot paths test
         self.enabled = (self.tracer.enabled or registry is not None
                         or audit is not None)
@@ -155,12 +203,28 @@ class Observability:
                    if audit_capacity > 0 else None)
 
     def attach(self, core: "SchedulerCore") -> None:
-        """Bind this bundle to one scheduler: the trace clock becomes the
-        core's discrete-event clock (virtual on sim; advanced by measured
-        wall time on real) and the worker tracks are declared."""
-        self.tracer.set_clock(lambda: core.now)
+        """Bind this bundle to one scheduler.  The backend says which
+        clock applies: on a ``host_clock`` backend (real engines) the
+        trace clock is ``time.perf_counter``, the backend and its engines
+        open their program spans on this tracer, and the process's
+        compilations are counted here; otherwise it is the core's
+        discrete-event clock.  The worker tracks are declared."""
+        backend = core.backend
+        self.host_clock = bool(getattr(backend, "host_clock", False))
+        if self.host_clock:
+            self.tracer.set_clock(time.perf_counter, host=True)
+            backend.bind_tracer(self.tracer)
+            _HOST_BUNDLES.add(self)
+            _listen_for_compiles()
+        else:
+            self.tracer.set_clock(lambda: core.now)
         for w in range(core.n_workers):
             self.tracer.declare_worker(w)
+
+    def _at(self, t_core: float) -> Optional[float]:
+        """The trace timestamp of a core-time moment: itself on core
+        time, the tracer's own clock (now) on the host clock."""
+        return None if self.host_clock else t_core
 
     # ------------------------------------------------------------------
     # export
@@ -215,9 +279,10 @@ class Observability:
     def on_arrival(self, core: "SchedulerCore", req: "Request") -> None:
         tr = self.tracer
         if tr.enabled:
-            tr.instant("arrival", core.now, args=dict(
+            tr.instant("arrival", self._at(core.now), args=dict(
                 rid=req.rid, input_len=req.effective_input_len))
-            tr.async_begin("request", req.rid, req.arrival, args=dict(
+            begin = req.host_submit if self.host_clock else req.arrival
+            tr.async_begin("request", req.rid, begin, args=dict(
                 rid=req.rid, input_len=req.input_len,
                 max_gen=req.max_gen, deadline=req.deadline))
         self._sample(core)
@@ -233,7 +298,7 @@ class Observability:
             self.ins.admission.inc(action=decision.action, reason=reason)
         if self.tracer.enabled:
             self.tracer.instant(
-                f"admission:{decision.action}", core.now,
+                f"admission:{decision.action}", self._at(core.now),
                 cat="admission",
                 args=dict(rid=rid, reason=reason,
                           predicted_completion=decision.predicted_completion))
@@ -274,17 +339,27 @@ class Observability:
                     loads={str(k): round(v, 9)
                            for k, v in sorted(loads.items())})
             if self.tracer.enabled:
-                self.tracer.instant("offload", core.now, cat="offload",
+                self.tracer.instant("offload", self._at(core.now),
+                                    cat="offload",
                                     args=dict(worker=w, rids=rids))
             loads[w] = loads.get(w, 0.0) + float(b.est_time)
         self._sample(core)
 
     def on_dispatch(self, core: "SchedulerCore", wid: int, b: "Batch",
-                    duration: float,
-                    prefill_dur: Optional[float]) -> None:
-        """One static slice dispatched: the span on the worker track plus
-        prefill/decode sub-spans when the backend measured them."""
+                    ex: "BatchExecution") -> None:
+        """One static slice dispatched.  On the host clock the backend's
+        ``slice`` span already sits in the trace and ``scls_slice_seconds``
+        observes its host seconds; on core time this emits the span on the
+        worker track plus prefill/decode sub-spans when the backend
+        measured them."""
         ins = self.ins
+        if self.host_clock:
+            if ins is not None:
+                ins.slices.inc()
+                if ex.host_seconds is not None:
+                    ins.slice_time.observe(ex.host_seconds)
+            return
+        duration, prefill_dur = ex.duration, ex.prefill_dur
         if ins is not None:
             ins.slices.inc()
             ins.slice_time.observe(duration)
@@ -316,6 +391,13 @@ class Observability:
             ins.reprefill_hist.observe(reprefill_tokens)
             if prefix_hit_tokens:
                 ins.prefix_hit.inc(prefix_hit_tokens)
+            if self.host_clock:
+                # the first token reaches the client at the end of the
+                # request's first slice (host clock, stamped by the backend)
+                for r in b.requests:
+                    if (r.n_schedules == 1 and r.host_submit is not None
+                            and r.host_ready is not None):
+                        ins.ttft.observe(r.host_ready - r.host_submit)
         # audit only slices where a prefix join actually happened, so
         # sharing-free runs produce byte-identical decision logs (the
         # golden-equivalence guard relies on this)
@@ -350,18 +432,33 @@ class Observability:
         ins = self.ins
         if ins is not None:
             ins.requests.inc(outcome=outcome)
-            if completed:
+            if completed and self.host_clock:
+                if req.host_submit is not None:
+                    ins.response.observe(time.perf_counter()
+                                         - req.host_submit)
+            elif completed:
                 ins.response.observe(core.now - req.arrival)
                 if req.first_token_time is not None:
                     ins.ttft.observe(req.first_token_time - req.arrival)
         tr = self.tracer
         if tr.enabled:
-            tr.instant("finalize", core.now, args=dict(rid=req.rid,
-                                                       outcome=outcome))
-            tr.async_end("request", req.rid, core.now,
+            tr.instant("finalize", self._at(core.now),
+                       args=dict(rid=req.rid, outcome=outcome))
+            tr.async_end("request", req.rid, self._at(core.now),
                          args=dict(outcome=outcome,
                                    generated=req.generated,
                                    n_schedules=req.n_schedules))
+
+    def on_compile(self, end: float, seconds: float,
+                   within: Optional[str]) -> None:
+        """One backend compilation ending at host time ``end``, inside the
+        program span ``within`` (innermost open; None outside any)."""
+        if self.ins is not None:
+            self.ins.compiles.inc()
+        tr = self.tracer
+        if tr.records_spans:
+            tr.complete("compile", end - seconds, seconds, cat="compile",
+                        args=dict(seconds=seconds, within=within))
 
 
 #: the one shared disabled bundle — every hook call site guards on

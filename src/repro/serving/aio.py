@@ -272,50 +272,57 @@ class AsyncSliceServer:
             if input_len is None:
                 input_len = int(prompt.shape[0])
         input_len = int(input_len)
-        arrival_t = self._arrival_now() if arrival is None else float(arrival)
-        if slo_ms is None:
-            slo_ms = self.default_slo_ms
-        deadline_t = deadline if deadline is not None else (
-            arrival_t + slo_ms / 1e3 if slo_ms is not None else None)
-        declared = (min(int(gen_len), int(max_gen))
-                    if gen_len is not None else int(max_gen))
+        # program span (repro.obs) around admission and the core's intake
+        with self.core.obs.tracer.span("submit", input_len=input_len) as sp:
+            arrival_t = (self._arrival_now() if arrival is None
+                         else float(arrival))
+            if slo_ms is None:
+                slo_ms = self.default_slo_ms
+            deadline_t = deadline if deadline is not None else (
+                arrival_t + slo_ms / 1e3 if slo_ms is not None else None)
+            declared = (min(int(gen_len), int(max_gen))
+                        if gen_len is not None else int(max_gen))
 
-        decision = self.admission.decide(
-            self.core, input_len=input_len, declared_gen=declared,
-            arrival=arrival_t, deadline=deadline_t,
-            allow_degrade=allow_degrade)
-        if not decision.accept:
-            self.core.n_rejected += 1
-            code = decision.reason_code or "other"
-            self.core.reject_reasons[code] = \
-                self.core.reject_reasons.get(code, 0) + 1
+            decision = self.admission.decide(
+                self.core, input_len=input_len, declared_gen=declared,
+                arrival=arrival_t, deadline=deadline_t,
+                allow_degrade=allow_degrade)
+            if not decision.accept:
+                self.core.n_rejected += 1
+                code = decision.reason_code or "other"
+                self.core.reject_reasons[code] = \
+                    self.core.reject_reasons.get(code, 0) + 1
+                if self.core.obs.enabled:
+                    # rejects carry rid=None — none was ever assigned
+                    self.core.obs.on_admission(
+                        self.core, decision, input_len=input_len,
+                        declared_gen=declared, deadline=deadline_t)
+                sp.set(action=decision.action)
+                raise AdmissionRejected(decision)
+            if decision.action == "degrade":
+                self.n_degraded += 1
+                max_gen = decision.max_gen
+                if gen_len is not None:
+                    gen_len = min(int(gen_len), max_gen)
+
+            rid = next(self._next_rid)
+            while rid in self.core._by_rid:  # replay() may have taken ids
+                rid = next(self._next_rid)
             if self.core.obs.enabled:
-                # rejects carry rid=None — none was ever assigned
                 self.core.obs.on_admission(
                     self.core, decision, input_len=input_len,
-                    declared_gen=declared, deadline=deadline_t)
-            raise AdmissionRejected(decision)
-        if decision.action == "degrade":
-            self.n_degraded += 1
-            max_gen = decision.max_gen
-            if gen_len is not None:
-                gen_len = min(int(gen_len), max_gen)
-
-        rid = next(self._next_rid)
-        while rid in self.core._by_rid:  # replay() may have taken ids
-            rid = next(self._next_rid)
-        if self.core.obs.enabled:
-            self.core.obs.on_admission(
-                self.core, decision, input_len=input_len,
-                declared_gen=declared, deadline=deadline_t, rid=rid)
-        req = Request(rid=rid, arrival=arrival_t, input_len=input_len,
-                      gen_len=None if gen_len is None else int(gen_len),
-                      max_gen=int(max_gen), prompt=prompt,
-                      deadline=deadline_t, session_id=session_id)
-        self.core.submit(req)
-        self.n_submitted += 1
-        h = AsyncRequestHandle(self, req)
-        self._handles[rid] = h
+                    declared_gen=declared, deadline=deadline_t, rid=rid)
+            req = Request(rid=rid, arrival=arrival_t, input_len=input_len,
+                          gen_len=None if gen_len is None else int(gen_len),
+                          max_gen=int(max_gen), prompt=prompt,
+                          deadline=deadline_t, session_id=session_id)
+            self.core.submit(req)
+            self.n_submitted += 1
+            h = AsyncRequestHandle(self, req)
+            self._handles[rid] = h
+            sp.set(rid=rid, action=decision.action)
+        if getattr(self.core.backend, "host_clock", False):
+            req.host_submit = req.host_ready = sp.t1
         self._kick()
         return h
 
@@ -506,7 +513,10 @@ class AsyncSliceServer:
                         pass
                     continue  # re-evaluate the earliest event either way
             try:
-                core.step()
+                # program span (repro.obs): the kind of event the step runs
+                with core.obs.tracer.span("pacer.step",
+                                          event=core._events[0][2]):
+                    core.step()
             except BaseException as e:
                 # a failed step would otherwise strand every waiter on an
                 # event that never fires: record it, wake everyone (their

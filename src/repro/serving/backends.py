@@ -49,6 +49,7 @@ from repro.core.memory import MemoryEstimator, PagedMemoryEstimator
 from repro.core.request import Batch, Request
 from repro.engine.static_engine import EOS_DRIVEN, StaticEngine
 from repro.kvcache import PageAllocator
+from repro.obs.trace import NULL_TRACER, Tracer
 
 # Per-request outcome dict keys (shared with StaticEngine.serve_batch
 # results): tokens, n_valid, invalid, pad, finished.
@@ -87,6 +88,9 @@ class BatchExecution:
     #: joins took references on — 0 outside kv_retain="request"
     prefix_hit_tokens: int = 0
     shared_blocks: int = 0
+    #: host seconds of the whole dispatch (the real backend's ``slice``
+    #: program span: planning, device stages and retention); None on sim
+    host_seconds: Optional[float] = None
 
 
 @runtime_checkable
@@ -242,9 +246,16 @@ class RealBackend:
 
     Continuous modes are not supported (the ILS baseline on real JAX
     lives in ``repro.engine.continuous_engine``).
+
+    Observability runs on the host clock here (``host_clock``): each
+    dispatch is a ``slice`` program span (``repro.obs``) carrying the
+    batch's rows, its Eq. 3/4 ``est_time`` and what the slice did, and
+    the engines' phases nest inside it.
     """
 
     supports_continuous = False
+    #: repro.obs stamps this backend's traces on time.perf_counter
+    host_clock = True
 
     def __init__(self, engines: Sequence[StaticEngine],
                  mem: Optional[MemoryEstimator] = None,
@@ -252,6 +263,7 @@ class RealBackend:
                  sched_bucket: int = 1,
                  kv_retain: str = "slice"):
         self.engines = list(engines)
+        self.tracer: Tracer = NULL_TRACER
         self.allocators: Optional[List[PageAllocator]] = None
         if kv_retain not in ("slice", "request"):
             raise ValueError(f"unknown kv_retain {kv_retain!r} "
@@ -302,9 +314,52 @@ class RealBackend:
                                                  mem.page_tokens)
                                    for _ in self.engines]
 
+    def bind_tracer(self, tracer: Tracer) -> None:
+        """Open this backend's and its engines' program spans on
+        ``tracer`` (``Observability.attach``)."""
+        self.tracer = tracer
+        for e in self.engines:
+            e.tracer = tracer
+
     # ------------------------------------------------------------------
     def run_batch(self, wid: int, batch: Batch,
                   prev_tokens: Sequence[Sequence[int]]) -> BatchExecution:
+        """One slice inside a ``slice`` program span; each member's
+        ``host_ready`` becomes the span's end.  The span's per-row args
+        list the rows in rid order (the dispatch log's)."""
+        reqs = sorted(batch.requests, key=lambda r: r.rid)
+        evicted = self._evictions()
+        with self.tracer.span(
+                "slice", wid=wid, rids=[r.rid for r in reqs],
+                rows=len(reqs), ctx=[r.effective_input_len for r in reqs],
+                fresh=[int(r.n_schedules == 0) for r in reqs],
+                input_len=int(batch.input_len),
+                slice_len=int(batch.slice_len),
+                est_time=float(batch.est_time)) as sp:
+            # host seconds each row waited since it was last ready (its
+            # submit ended, or its previous slice did); -1 when unknown
+            sp.set(waited=[round(sp.t0 - r.host_ready, 6)
+                           if r.host_ready is not None else -1
+                           for r in reqs])
+            remaining = {r.rid: r.remaining_gen for r in reqs}
+            ex = self._run_batch(wid, batch, prev_tokens)
+            valid = {r.rid: min(int(o["n_valid"]), remaining[r.rid])
+                     for r, o in zip(batch.requests, ex.per_request)}
+            sp.set(steps=ex.steps, valid=[valid[r.rid] for r in reqs],
+                   reprefill=ex.reprefill_tokens,
+                   prefix_hit=ex.prefix_hit_tokens,
+                   evictions=self._evictions() - evicted,
+                   wall=ex.duration)
+        ex.host_seconds = sp.duration
+        for r in reqs:
+            r.host_ready = sp.t1
+        return ex
+
+    def _evictions(self) -> int:
+        return sum(getattr(e, "n_evictions", 0) for e in self.engines)
+
+    def _run_batch(self, wid: int, batch: Batch,
+                   prev_tokens: Sequence[Sequence[int]]) -> BatchExecution:
         eng = self.engines[wid]
         prompts = [r.prompt for r in batch.requests]
         # gen_len=None → EOS-driven: the engine detects the model's own EOS

@@ -366,6 +366,22 @@ class SchedulerCore:
             return  # superseded by a tick re-armed for an earlier time
         self._armed_tick = None
         reqs, self.pool = self.pool, []
+        # program span (repro.obs): Alg. 1 batching, Eq. 10-11 placement
+        # and, on the real backend, the dispatch of the slices it starts
+        with self.obs.tracer.span("sched.tick", pool=len(reqs),
+                                  rids=[r.rid for r in reqs]) as sp:
+            n_batches = self._schedule(reqs)
+            sp.set(batches=n_batches, rows=len(reqs))
+        if self.s.adaptive_interval:
+            dt = next_interval(self.offloader.min_load(), self.s.lam,
+                               self.s.gamma)
+        else:
+            dt = self.s.gamma
+        if self._more_work_expected():
+            self._push_tick(self.now + dt)
+
+    def _schedule(self, reqs: List[Request]) -> int:
+        """Batch and place the drained pool; returns the batches formed."""
         if reqs and self.s.mode == "cont_scls":
             # beyond-paper: max-min placement of S-token *leases*; the
             # worker itself is a continuous-batching engine, so the load a
@@ -385,31 +401,23 @@ class SchedulerCore:
                 wk.pending.append(b.requests[0])
                 if not wk.busy:
                     self._continuous_step(wk)
-        elif reqs and self.s.mode == "pred":
+            return len(singles)
+        if not reqs:
+            return 0
+        if self.s.mode == "pred":
             # SCLS-PRED / ORACLE: calibrated predicted remaining-length
             # caps pick the buckets and per-batch slice lengths
             batches = self.pred.batches(reqs, self.est, self.mem)
-            for w, b in self._assign(batches):
-                wk = self.workers[w]
-                wk.queue.append(b)
-                if not wk.busy:
-                    self._start_batch(wk)
-        elif reqs:
+        else:
             cap = self.s.dp_cap if self.s.dp_cap else None
             batches = dp_batch(reqs, self.s.slice_len, self.est, self.mem,
                                max_batch_size=cap, packing=self.s.packing)
-            for w, b in self._assign(batches):
-                wk = self.workers[w]
-                wk.queue.append(b)
-                if not wk.busy:
-                    self._start_batch(wk)
-        if self.s.adaptive_interval:
-            dt = next_interval(self.offloader.min_load(), self.s.lam,
-                               self.s.gamma)
-        else:
-            dt = self.s.gamma
-        if self._more_work_expected():
-            self._push_tick(self.now + dt)
+        for w, b in self._assign(batches):
+            wk = self.workers[w]
+            wk.queue.append(b)
+            if not wk.busy:
+                self._start_batch(wk)
+        return len(batches)
 
     def _assign(self, batches: List[Batch]) -> List[Tuple[int, Batch]]:
         """Offloader placement with decision audit: the pre-assignment
@@ -462,7 +470,7 @@ class SchedulerCore:
         ex = self.backend.run_batch(w.wid, b, prev)
         w.busy = True
         if self.obs.enabled:
-            self.obs.on_dispatch(self, w.wid, b, ex.duration, ex.prefill_dur)
+            self.obs.on_dispatch(self, w.wid, b, ex)
         self._push(self.now + ex.duration, "batch_done", (w.wid, b, ex))
 
     def _on_batch_done(self, payload: Tuple[int, Batch, object]) -> None:

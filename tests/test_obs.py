@@ -326,3 +326,240 @@ def test_compute_metrics_carries_reject_reasons():
     assert row["n_rejected_deadline"] == 5
     bare = compute_metrics("x", [], 10.0, [1.0], [1], 0, 0)
     assert bare.n_rejected_memory == bare.n_rejected_deadline == 0
+
+
+# ---------------------------------------------------------------------------
+# program spans on the real backend (host clock)
+# ---------------------------------------------------------------------------
+def _toy_estimator():
+    from repro.core.estimator import LatencyCoeffs, ServingTimeEstimator
+    return ServingTimeEstimator(LatencyCoeffs(1e-6, 1e-4, 1e-6, 1e-3),
+                                LatencyCoeffs(1e-8, 1e-5, 1e-7, 1e-3))
+
+
+@pytest.fixture(scope="module")
+def real_traced(tmp_path_factory):
+    """Four requests served through ``AsyncSliceServer`` over a retain-mode
+    ``RealBackend`` (one persistent paged engine) with tracing on; the
+    host clock read before and after the run."""
+    import asyncio
+    import time
+
+    import jax
+    import numpy as np
+    from repro.configs import get_config
+    from repro.engine.static_engine import StaticEngine
+    from repro.models.registry import get_model
+
+    model = get_model(get_config("llama3.2-1b", reduced=True))
+    params = model.init(jax.random.PRNGKey(0))
+    cfg = ServingConfig(strategy="scls", backend="real", kv_layout="paged",
+                        kv_retain="request", page_tokens=16, slice_len=8,
+                        max_gen=32, gamma=0.25, m_available=64e6,
+                        mem_bucket=8, trace_out=str(
+                            tmp_path_factory.mktemp("t") / "t.json"))
+    mem = cfg.memory_estimator(model.kv_bytes_per_token())
+    eng = StaticEngine(model, params, eos_id=1, len_bucket=8,
+                       kv_layout="paged", page_tokens=16,
+                       kv_pool_tokens=mem.total_blocks * 16)
+    server = cfg.build_real([eng], _toy_estimator(), mem)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(2, 200, size=n).astype(np.int32)
+               for n in (9, 17, 12, 5)]
+
+    async def main():
+        aio = server.aio
+        hs = [aio.submit(p, gen_len=g, max_gen=32)
+              for p, g in zip(prompts, (12, 5, 20, 3))]
+        return [await h.result() for h in hs]
+
+    t_before = time.perf_counter()
+    reqs = asyncio.run(main())
+    t_after = time.perf_counter()
+    assert all(r.done for r in reqs)
+    events = server.core.obs.tracer.to_dict()["traceEvents"]
+    return dict(server=server, reqs=reqs, events=events,
+                t_before=t_before, t_after=t_after)
+
+
+def _spans(events):
+    return {e["args"]["span_id"]: e for e in events
+            if e["ph"] == "X" and e.get("cat") == "span"}
+
+
+def test_real_spans_nest_engine_in_slice_in_tick_in_pacer(real_traced):
+    spans = _spans(real_traced["events"])
+    by_name = {}
+    for e in spans.values():
+        by_name.setdefault(e["name"], []).append(e)
+    assert {"submit", "pacer.step", "sched.tick", "slice", "engine.plan",
+            "engine.prefill", "engine.decode",
+            "engine.retain"} <= set(by_name)
+    assert len(by_name["submit"]) == 4
+
+    def parent(e):
+        return spans.get(e["args"]["parent_id"])
+
+    def inside(c, p):
+        return (c["ts"] >= p["ts"] - 1e-3
+                and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-3)
+
+    for name in ("engine.plan", "engine.prefill", "engine.decode",
+                 "engine.retain"):
+        for e in by_name[name]:
+            p = parent(e)
+            assert p["name"] == "slice" and inside(e, p)
+            assert e["tid"] == p["tid"] == worker_tid(0)
+    for s in by_name["slice"]:
+        assert parent(s)["name"] in ("sched.tick", "pacer.step")
+        assert inside(s, parent(s))
+    chains = [s for s in by_name["slice"]
+              if parent(s)["name"] == "sched.tick"
+              and parent(parent(s))["name"] == "pacer.step"
+              and parent(parent(parent(s))) is None]
+    assert chains, "no slice dispatched from a tick inside a pacer step"
+    # the dispatch log still comes back from the slice spans
+    assert validate_obs.validate_trace(
+        {"traceEvents": real_traced["events"]}) == []
+    assert validate_obs.trace_slice_log(
+        {"traceEvents": real_traced["events"]}) \
+        == real_traced["server"].core.batch_log
+
+
+def test_real_span_timestamps_are_host_seconds(real_traced):
+    """Every event of the real backend's trace, spans and instants and
+    the requests' async lifecycles, sits on ``time.perf_counter``."""
+    lo, hi = real_traced["t_before"] * 1e6, real_traced["t_after"] * 1e6
+    timed = [e for e in real_traced["events"] if e["ph"] != "M"]
+    assert timed
+    for e in timed:
+        assert lo <= e["ts"] <= hi, e
+        assert e["ts"] + e.get("dur", 0.0) <= hi
+    begins = [e for e in timed if e["ph"] == "b"]
+    submits = {e["args"]["rid"]: e for e in timed if e["name"] == "submit"}
+    assert len(begins) == 4
+    for b in begins:  # a lifecycle opens where its submit span ends
+        s = submits[b["id"]]
+        assert b["ts"] == pytest.approx(s["ts"] + s["dur"], abs=1.0)
+
+
+def test_real_slice_span_args_are_the_batch_own(real_traced):
+    """rids and the Eq. 3/4 ``est_time`` of each slice span are those the
+    scheduler placed (the decision audit's offload records), in order."""
+    events = real_traced["events"]
+    slices = sorted((e for e in events
+                     if e["ph"] == "X" and e["name"] == "slice"),
+                    key=lambda e: e["ts"])
+    offloads = real_traced["server"].core.obs.audit.query(kind="offload")
+    assert len(slices) == len(offloads) >= 2
+    for s, o in zip(slices, offloads):
+        a = s["args"]
+        assert sorted(a["rids"]) == o["rids"]
+        assert a["est_time"] == o["est_time"]
+        assert a["rows"] == len(a["rids"]) == len(a["valid"])
+        assert 1 <= a["steps"] <= 8
+    reqs = {r.rid: r for r in real_traced["reqs"]}
+    firsts = {}
+    for s in slices:
+        for rid, fresh in zip(s["args"]["rids"], s["args"]["fresh"]):
+            if fresh:
+                assert rid not in firsts
+                firsts[rid] = s
+    assert set(firsts) == set(reqs)
+    # every valid token of every request came out of some slice span
+    valid = {}
+    for s in slices:
+        for rid, v in zip(s["args"]["rids"], s["args"]["valid"]):
+            valid[rid] = valid.get(rid, 0) + v
+    assert valid == {rid: r.generated for rid, r in reqs.items()}
+
+
+def test_real_latency_histograms_observe_host_seconds(real_traced):
+    ins = real_traced["server"].core.obs.ins
+    span_s = (real_traced["t_after"] - real_traced["t_before"])
+    assert ins.ttft.count() == ins.response.count() == 4
+    assert 0 < ins.ttft.sum() <= ins.response.sum() <= 4 * span_s
+    slices = [e for e in real_traced["events"] if e["name"] == "slice"]
+    assert ins.slice_time.count() == len(slices)
+    assert ins.slice_time.sum() == pytest.approx(
+        sum(e["dur"] for e in slices) / 1e6, rel=1e-3)
+
+
+def test_compile_listener_counts_each_new_shape_once():
+    import time
+    from types import SimpleNamespace
+
+    import jax
+    import numpy as np
+    obs = Observability.standard(trace=True)
+    core = SimpleNamespace(n_workers=1, backend=SimpleNamespace(
+        host_clock=True, bind_tracer=lambda tracer: None))
+    obs.attach(core)
+    salt = float(time.perf_counter_ns() % 9973)  # a program no cache holds
+    f = jax.jit(lambda x: x * salt + 1.0)
+    before = obs.ins.compiles.value()
+    with obs.tracer.span("probe"):
+        f(np.ones(3, np.float32)).block_until_ready()
+    assert obs.ins.compiles.value() == before + 1
+    with obs.tracer.span("probe"):
+        f(np.ones(3, np.float32)).block_until_ready()
+    assert obs.ins.compiles.value() == before + 1
+    f(np.ones(5, np.float32)).block_until_ready()
+    assert obs.ins.compiles.value() == before + 2
+    comp = [e for e in obs.tracer.to_dict()["traceEvents"]
+            if e["name"] == "compile"]
+    assert [e["args"]["within"] for e in comp] == ["probe", None]
+    assert all(e["dur"] > 0 for e in comp)
+
+
+def test_disabled_and_sim_tracers_record_no_spans():
+    """Spans cost a ``TraceAnnotation`` and no event when the tracer is
+    off, or on the simulator's core clock (its trace stays byte-exact)."""
+    with NULL_TRACER.span("probe", rids=[1, 2]) as sp:
+        pass
+    assert len(NULL_TRACER) == 0 and sp.t1 >= sp.t0
+    sim = Tracer(clock=lambda: 0.0)
+    with sim.span("probe", rids=[1]):
+        with sim.span("inner"):
+            pass
+    assert len(sim) == 0
+    true_lat, est, mem = default_sim_environment("hf")
+    server = ServingConfig(strategy="scls", workers=2,
+                           trace_out="unused.json").build_sim(
+        true_lat, est, mem)
+    server.submit(input_len=32, gen_len=40)
+    server.drain()
+    events = server.core.obs.tracer.to_dict()["traceEvents"]
+    assert not [e for e in events if e.get("cat") == "span"]
+
+
+def test_spans_reach_the_profiler_trace_with_their_args(tmp_path):
+    """Inside a profiler session each span is a ``scls.<name>`` event of
+    the host plane, with its args, the late ones included (lists as
+    space-separated strings)."""
+    import glob
+
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with NULL_TRACER.span("outer", rids=[3, 4, 5]) as sp:
+            with NULL_TRACER.span("inner", rows=2):
+                pass
+            sp.set(steps=7, valid=[1, 2, 3])
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path[0])
+    found = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("scls."):
+                    found[e.name] = (e.start_ns, e.duration_ns,
+                                     dict(e.stats))
+    assert set(found) == {"scls.outer", "scls.inner"}
+    outer, inner = found["scls.outer"], found["scls.inner"]
+    assert outer[2] == {"rids": "3 4 5", "steps": 7, "valid": "1 2 3"}
+    assert inner[2] == {"rows": 2}
+    assert outer[0] <= inner[0] and \
+        inner[0] + inner[1] <= outer[0] + outer[1]
