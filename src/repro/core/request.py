@@ -45,6 +45,11 @@ class Request:
     n_schedules: int = 0
     finish_time: Optional[float] = None
     first_token_time: Optional[float] = None
+    #: core time the request last became ready to dispatch: the end of
+    #: the slice that returned it to the pool (None: it has not run, and
+    #: its arrival stands).  Orders an inline backend's batches
+    #: (``SchedulerCore._start_batch``)
+    ready_at: Optional[float] = None
     # accounting (paper Figs. 13/16/19)
     pad_tokens: int = 0
     invalid_tokens: int = 0

@@ -18,7 +18,11 @@ rejected / batched with those peers / placed on worker 3":
     constraint enforced;
   * ``kind="offload"`` — one event per placement: the chosen worker and
     every worker's Eq. 11 load *at decision time* (reconstructed from
-    the offloader's greedy bookkeeping order).
+    the offloader's greedy bookkeeping order);
+  * ``kind="withdraw"`` — a placed batch that had not started, taken
+    back when its worker freed on a backend that runs slices inline
+    (``SchedulerCore._rebind``): worker, rids and the ``est_time`` its
+    Eq. 11 load gave back.
 
 Events are plain dicts (JSON-ready) with a monotone ``seq`` and the core
 timestamp ``ts``; the ring drops the oldest events at capacity so a

@@ -37,7 +37,8 @@ Metric catalog (all ``scls_`` namespaced; catalog with units in
               ``scls_requests_total{outcome}``,
               ``scls_admission_total{action,reason}``,
               ``scls_reprefill_tokens_total``,
-              ``scls_prefix_hit_tokens_total``, ``scls_compiles_total``
+              ``scls_prefix_hit_tokens_total``, ``scls_compiles_total``,
+              ``scls_dispatch_rebinds_total``
   gauges      ``scls_queue_depth``, ``scls_in_flight_slices``,
               ``scls_kv_free_pages``, ``scls_kv_retained_blocks``,
               ``scls_kv_evictions``, ``scls_kv_shared_blocks``
@@ -148,6 +149,11 @@ class ServingInstruments:
             "scls_compiles_total",
             "Backend (XLA) compilations in this process while serving on "
             "the real backend")
+        self.rebinds = registry.counter(
+            "scls_dispatch_rebinds_total",
+            "Unstarted batches withdrawn from a worker's queue when it "
+            "freed and formed again with the rows its slice returned "
+            "(backends that run a slice inline)")
         self.queue_depth = registry.gauge(
             "scls_queue_depth",
             "Requests waiting to be dispatched (pool + worker queues)")
@@ -408,6 +414,20 @@ class Observability:
                 prefix_hit_tokens=int(prefix_hit_tokens),
                 shared_blocks=int(shared_blocks))
         self._sample(core)
+
+    def on_rebind(self, core: "SchedulerCore", wid: int,
+                  withdrawn: Sequence["Batch"]) -> None:
+        """Worker ``wid`` freed and withdrew its unstarted batches to be
+        formed again (``SchedulerCore._rebind``): one ``withdraw`` audit
+        event each, undoing its ``offload`` placement."""
+        if self.ins is not None:
+            self.ins.rebinds.inc(len(withdrawn))
+        if self.audit is not None:
+            for b in withdrawn:
+                self.audit.record(
+                    "withdraw", core.now, worker=wid,
+                    rids=sorted(r.rid for r in b.requests),
+                    est_time=float(b.est_time))
 
     def on_cont_dispatch(self, core: "SchedulerCore", wid: int,
                          rids: Sequence[int], duration: float) -> None:

@@ -54,8 +54,15 @@ class QuantileCalibrator:
 
     def cap(self, req, raw_remaining: float) -> int:
         """Conservative remaining-length cap for ``req`` (>= 1 token)."""
-        self._pending.setdefault(req.rid, []).append(
-            (max(float(raw_remaining), 1.0), int(req.generated)))
+        pending = self._pending.setdefault(req.rid, [])
+        pred = (max(float(raw_remaining), 1.0), int(req.generated))
+        if pending and pending[-1][1] == pred[1]:
+            # predicted again at the same progress (its batch was taken
+            # back before it ran): the new prediction replaces the one it
+            # supersedes, so each slice run is scored once
+            pending[-1] = pred
+        else:
+            pending.append(pred)
         budget = max(int(req.max_gen) - int(req.generated), 1)
         return int(np.clip(round(raw_remaining * self.scale), 1, budget))
 

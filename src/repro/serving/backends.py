@@ -99,6 +99,9 @@ class Backend(Protocol):
 
     #: whether continuous-batching modes (ILS / SCLS-CB) can run here
     supports_continuous: bool
+    #: whether ``run_batch`` runs the slice to completion before it
+    #: returns; the core then forms a worker's next batch when it frees
+    runs_inline: bool
 
     def run_batch(self, wid: int, batch: Batch,
                   prev_tokens: Sequence[Sequence[int]]) -> BatchExecution:
@@ -150,6 +153,8 @@ class SimBackend:
     """
 
     supports_continuous = True
+    #: slices run on the virtual clock, in parallel across workers
+    runs_inline = False
 
     def __init__(self, true_lat: ServingTimeEstimator,
                  noise_sigma: float = 0.0, seed: int = 0):
@@ -254,6 +259,8 @@ class RealBackend:
     """
 
     supports_continuous = False
+    #: ``run_batch`` executes the slice on the engine before it returns
+    runs_inline = True
     #: repro.obs stamps this backend's traces on time.perf_counter
     host_clock = True
 

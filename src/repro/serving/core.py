@@ -22,6 +22,19 @@ Worker modes mirror the strategy modes (``repro.core.schedulers``):
   * cont_scls  — SCLS-CB: S-token slice leases on continuous batching
                  (sim backend only).
 
+Inline dispatch (central and pred modes): a backend whose ``run_batch``
+runs the slice to completion before it returns (``runs_inline``, the
+real engines) cannot start a queued batch before the running one ends,
+so binding batches to a worker at the Eq. 12 tick buys nothing there.
+When such a worker frees, its unstarted batches are withdrawn (their
+Eq. 11 load with them), the pool (the returned rows included) is
+batched and placed again inside a ``sched.tick`` span, and the worker
+starts the batch holding the row that has waited longest since its last
+slice ended (or since it arrived), so groups split at the memory bound
+take turns slice by slice.  The simulator
+(N parallel instances on one virtual clock) keeps the paper's
+tick-time binding, which the golden dispatch logs pin.
+
 Beyond the offline ``run()``, the core is an *online* machine: requests
 can be submitted at any time (``submit``), observed incrementally
 (``token_log`` grows per slice), and cancelled mid-flight (``cancel`` —
@@ -60,6 +73,12 @@ CONTINUOUS_MODES = ("continuous", "cont_scls")
 # batch_log entry tags (the equivalence-test fingerprint format)
 _LOG_STATIC = "static"
 _LOG_CONT = "cont"
+
+
+def _ready_key(r: Request) -> Tuple[float, int]:
+    """When ``r`` became ready to dispatch (its last slice's end, else
+    its arrival), rid to break ties: an inline worker's start order."""
+    return (r.arrival if r.ready_at is None else r.ready_at, r.rid)
 
 
 class WorkerState:
@@ -115,6 +134,9 @@ class SchedulerCore:
         self.mem = mem
         self.ils_span = ils_span
         self.workers = [WorkerState(w) for w in range(n_workers)]
+        #: the backend runs each slice inside ``run_batch`` (module
+        #: docstring, inline dispatch)
+        self._inline = backend.runs_inline
         self.offloader: Offloader = (
             MaxMinOffloader(n_workers) if strategy.offload == "maxmin"
             else RoundRobinOffloader(n_workers))
@@ -365,13 +387,7 @@ class SchedulerCore:
                 and t != self._armed_tick):
             return  # superseded by a tick re-armed for an earlier time
         self._armed_tick = None
-        reqs, self.pool = self.pool, []
-        # program span (repro.obs): Alg. 1 batching, Eq. 10-11 placement
-        # and, on the real backend, the dispatch of the slices it starts
-        with self.obs.tracer.span("sched.tick", pool=len(reqs),
-                                  rids=[r.rid for r in reqs]) as sp:
-            n_batches = self._schedule(reqs)
-            sp.set(batches=n_batches, rows=len(reqs))
+        self._schedule_pool()
         if self.s.adaptive_interval:
             dt = next_interval(self.offloader.min_load(), self.s.lam,
                                self.s.gamma)
@@ -379,6 +395,18 @@ class SchedulerCore:
             dt = self.s.gamma
         if self._more_work_expected():
             self._push_tick(self.now + dt)
+
+    def _schedule_pool(self, withdrawn: Optional[int] = None) -> None:
+        """Drain the pool into :meth:`_schedule` inside the ``sched.tick``
+        program span (repro.obs): Alg. 1 batching, Eq. 10-11 placement
+        and, on the real backend, the dispatch of the slices it starts;
+        ``withdrawn`` marks a worker's free moment (:meth:`_rebind`)."""
+        reqs, self.pool = self.pool, []
+        with self.obs.tracer.span("sched.tick", pool=len(reqs),
+                                  rids=[r.rid for r in reqs],
+                                  withdrawn=withdrawn) as sp:
+            n_batches = self._schedule(reqs)
+            sp.set(batches=n_batches, rows=len(reqs))
 
     def _schedule(self, reqs: List[Request]) -> int:
         """Batch and place the drained pool; returns the batches formed."""
@@ -412,11 +440,13 @@ class SchedulerCore:
             cap = self.s.dp_cap if self.s.dp_cap else None
             batches = dp_batch(reqs, self.s.slice_len, self.est, self.mem,
                                max_batch_size=cap, packing=self.s.packing)
-        for w, b in self._assign(batches):
-            wk = self.workers[w]
-            wk.queue.append(b)
-            if not wk.busy:
-                self._start_batch(wk)
+        placed = self._assign(batches)
+        for w, b in placed:
+            self.workers[w].queue.append(b)
+        # idle workers start once all their batches are queued, so an
+        # inline backend's worker can pick among them (_start_batch)
+        for w in dict.fromkeys(w for w, _ in placed):
+            self._start_batch(self.workers[w])
         return len(batches)
 
     def _assign(self, batches: List[Batch]) -> List[Tuple[int, Batch]]:
@@ -461,7 +491,17 @@ class SchedulerCore:
     def _start_batch(self, w: WorkerState) -> None:
         if w.busy or not w.queue:
             return
-        b = w.queue.popleft()
+        i = 0
+        if self._inline:
+            # the batch holding the row that has waited longest since it
+            # last ran (or arrived) goes first: groups split at the
+            # Eq. 5-9 bound take turns, and none is re-formed behind the
+            # others at every free moment
+            i = min(range(len(w.queue)),
+                    key=lambda i: min(_ready_key(r)
+                                      for r in w.queue[i].requests))
+        b = w.queue[i]
+        del w.queue[i]
         self.peak_parallel = max(self.peak_parallel, b.size)
         self.batch_log.append(
             [_LOG_STATIC, w.wid, sorted(r.rid for r in b.requests),
@@ -514,6 +554,7 @@ class SchedulerCore:
                 # engine's finished flag
                 self._finalize(r, completed=True)
             else:
+                r.ready_at = self.now
                 unfinished.append(r)
                 self._notify("slice", r)
         self.offloader.on_batch_complete(wid, b.est_time)
@@ -532,8 +573,29 @@ class SchedulerCore:
                                    ex.prefix_hit_tokens, ex.shared_blocks)
         if self.s.mode == "perreq" and w.pending and not w.busy:
             self._start_static_fcfs(w)
+        elif self._inline and self.s.mode in ("central", "pred"):
+            self._rebind(w)
         elif w.queue:
             self._start_batch(w)
+
+    def _rebind(self, w: WorkerState) -> None:
+        """Inline backend, worker ``w`` just freed (module docstring):
+        withdraw its unstarted batches into the pool, which already holds
+        the rows its slice returned, and schedule the pool again."""
+        withdrawn = list(w.queue)
+        w.queue.clear()
+        for b in withdrawn:
+            # the Eq. 11 load charged at placement leaves with the batch
+            self.offloader.on_batch_complete(w.wid, b.est_time)
+            for r in b.requests:
+                if r.rid in self._cancelled:
+                    self._finalize(r, completed=False)
+                else:
+                    self.pool.append(r)
+        if withdrawn and self.obs.enabled:
+            self.obs.on_rebind(self, w.wid, withdrawn)
+        if self.pool:
+            self._schedule_pool(withdrawn=len(withdrawn))
 
     # ------------------------------------------------------------------
     # continuous batching (ILS / SCLS-CB; sim backend only)

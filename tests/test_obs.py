@@ -445,12 +445,23 @@ def test_real_span_timestamps_are_host_seconds(real_traced):
 
 def test_real_slice_span_args_are_the_batch_own(real_traced):
     """rids and the Eq. 3/4 ``est_time`` of each slice span are those the
-    scheduler placed (the decision audit's offload records), in order."""
+    scheduler placed (the decision audit's offload records), in order,
+    less the placements withdrawn before they started (the audit's
+    withdraw records: the real backend runs slices inline)."""
     events = real_traced["events"]
     slices = sorted((e for e in events
                      if e["ph"] == "X" and e["name"] == "slice"),
                     key=lambda e: e["ts"])
-    offloads = real_traced["server"].core.obs.audit.query(kind="offload")
+    offloads = []
+    for ev in real_traced["server"].core.obs.audit.to_list():
+        key = (ev.get("worker"), ev.get("rids"), ev.get("est_time"))
+        if ev["kind"] == "offload":
+            offloads.append(ev)
+        elif ev["kind"] == "withdraw":
+            placed = [(o["worker"], o["rids"], o["est_time"])
+                      for o in offloads]
+            assert key in placed, ev  # only a placed batch is withdrawn
+            del offloads[placed.index(key)]
     assert len(slices) == len(offloads) >= 2
     for s, o in zip(slices, offloads):
         a = s["args"]

@@ -17,6 +17,7 @@ from repro.core.memory import (AnalyticMemoryEstimator, LLAMA2_13B_DELTA,
                                PagedMemoryEstimator)
 from repro.core.request import Request
 from repro.core.schedulers import make_strategy
+from repro.obs import Observability
 from repro.serving import (ServingConfig, SimBackend, SchedulerCore,
                            default_sim_environment)
 
@@ -595,3 +596,220 @@ def test_static_engine_per_row_eos_sentinel(real_env):
         assert toks.index(1) == len(toks) - 1 and r1["finished"]
     else:
         assert r1["n_valid"] == res.steps
+
+
+# ---------------------------------------------------------------------------
+# inline dispatch: a backend whose run_batch runs the slice before it
+# returns forms a worker's next batch when the worker frees
+# ---------------------------------------------------------------------------
+class InlineSim(SimBackend):
+    """The simulator's physics behind a backend that says it runs each
+    slice inline, as the real engines do."""
+
+    runs_inline = True
+
+
+def _inline_core(sim_env, mem=None, n_workers=1, **strategy):
+    true_lat, est, sim_mem = sim_env
+    return SchedulerCore(make_strategy("scls", **strategy),
+                         InlineSim(true_lat), n_workers, est,
+                         mem if mem is not None else sim_mem,
+                         obs=Observability.standard())
+
+
+def _step_until(core, cond):
+    while not cond():
+        assert core.step(), "event queue ran dry"
+
+
+def _submit_during_first_slice(core, reqs):
+    """Submit ``reqs`` once the first slice is dispatched, halfway through
+    its virtual run: what clients held off by the running slice do."""
+    _step_until(core, lambda: core.batch_log)
+    done_at = min(t for t, _, kind, _ in core._events if kind == "batch_done")
+    for r in reqs:
+        core.submit(r, arrival=0.5 * (core.now + done_at))
+
+
+def test_backends_say_whether_they_run_inline():
+    from repro.serving.backends import RealBackend
+    assert SimBackend.runs_inline is False
+    assert RealBackend.runs_inline is True
+
+
+def test_inline_requests_submitted_mid_slice_join_its_unfinished_rows(sim_env):
+    core = _inline_core(sim_env, slice_len=8)
+    core.submit(Request(rid=0, arrival=0.0, input_len=16, gen_len=20))
+    _submit_during_first_slice(
+        core, [Request(rid=i, arrival=0.0, input_len=16, gen_len=4)
+               for i in (1, 2)])
+    core.run_until_idle()
+    assert all(r.done for r in core.requests)
+    # the tick at row 1's arrival queued [1] behind the running slice and
+    # row 2 arrived after it; the worker withdrew [1] when it freed and
+    # served both newcomers with row 0, in one slice
+    assert [e[2] for e in core.batch_log[:2]] == [[0], [0, 1, 2]]
+    assert core.obs.ins.rebinds.value() == 1
+    withdrawn = core.obs.audit.query(kind="withdraw")
+    assert [(w["worker"], w["rids"]) for w in withdrawn] == [(0, [1])]
+
+
+def test_sim_backend_binds_mid_slice_arrivals_at_the_tick(sim_env):
+    """The same arrivals on the simulator keep the paper's tick-time
+    binding: row 1's batch, queued behind the running slice, runs before
+    row 0 rejoins."""
+    true_lat, est, mem = sim_env
+    core = SchedulerCore(make_strategy("scls", slice_len=8),
+                         SimBackend(true_lat), 1, est, mem,
+                         obs=Observability.standard())
+    core.submit(Request(rid=0, arrival=0.0, input_len=16, gen_len=20))
+    _submit_during_first_slice(
+        core, [Request(rid=i, arrival=0.0, input_len=16, gen_len=4)
+               for i in (1, 2)])
+    core.run_until_idle()
+    assert [e[2] for e in core.batch_log[:3]] == [[0], [1], [0, 2]]
+    assert core.obs.ins.rebinds.value() == 0
+    assert core.obs.audit.query(kind="withdraw") == []
+
+
+def test_inline_split_at_memory_bound_starts_the_earliest_arrival(sim_env):
+    # (L + S) * N <= 48 tokens: at most two rows of up to 16 tokens
+    mem = AnalyticMemoryEstimator(delta_bytes=1.0, m_available=48.0)
+    core = _inline_core(sim_env, mem=mem, slice_len=8)
+    core.submit(Request(rid=0, arrival=0.0, input_len=16, gen_len=4))
+    _step_until(core, lambda: core.batch_log)
+    done_at = min(t for t, _, kind, _ in core._events if kind == "batch_done")
+    # three newcomers while row 0 runs its only slice: the first has the
+    # longest prompt, so no batch holds all three
+    for rid, n, f in ((1, 16, 0.2), (2, 4, 0.4), (3, 4, 0.6)):
+        core.submit(Request(rid=rid, arrival=0.0, input_len=n, gen_len=4),
+                    arrival=core.now + f * (done_at - core.now))
+    _step_until(core, lambda: len(core.batch_log) == 2)
+    # at the free moment Alg. 1 split the pool at the bound and max-min
+    # placed the larger batch first; the worker started row 1's
+    offloads = core.obs.audit.query(kind="offload")
+    assert [o["rids"] for o in offloads[-2:]] == [[2, 3], [1]]
+    assert core.batch_log[1][2] == [1]
+    assert [sorted(r.rid for r in b.requests)
+            for b in core.workers[0].queue] == [[2, 3]]
+    core.run_until_idle()
+    assert all(r.done for r in core.requests)
+
+
+def test_inline_split_groups_take_turns_slice_by_slice(sim_env):
+    """A split that persists: rows that wait in the pool go before the
+    rows the freed worker's slice just returned, so neither group is
+    withdrawn and re-formed behind the other until it finishes."""
+    # (L + S) * N <= 56 tokens: two rows of up to 20 tokens, three of 10
+    mem = AnalyticMemoryEstimator(delta_bytes=1.0, m_available=56.0)
+    core = _inline_core(sim_env, mem=mem, slice_len=8)
+    for rid in range(3):
+        core.submit(Request(rid=rid, arrival=0.0, input_len=4, gen_len=24))
+    core.run_until_idle()
+    assert all(r.done and r.n_schedules == 3 for r in core.requests)
+    # the first tick saw row 0 alone; rows 1 and 2 then pooled together
+    assert [e[2] for e in core.batch_log] == [[0], [1, 2]] * 3
+    assert core.obs.ins.rebinds.value() > 0
+
+
+def test_inline_pred_scores_one_prediction_per_slice(sim_env):
+    """SCLS-PRED on an inline backend: a batch taken back and formed
+    again predicts its rows a second time at the same progress; the
+    calibrator keeps one prediction per slice a request ran."""
+    true_lat, est, mem = sim_env
+    core = SchedulerCore(make_strategy("scls-pred", slice_len=16,
+                                       max_gen=256),
+                         InlineSim(true_lat), 1, est, mem,
+                         obs=Observability.standard())
+    cal, scored = core.calibrator, {}
+    observe = cal.observe
+
+    def observe_and_count(req):
+        scored[req.rid] = len(cal._pending.get(req.rid, ()))
+        observe(req)
+
+    cal.observe = observe_and_count
+    for r in generate_trace(8.0, 10.0, CODEFUSE, seed=3):
+        r.gen_len = min(r.gen_len, 256)
+        core.submit(r)
+    core.run_until_idle()
+    assert all(r.done for r in core.requests)
+    assert core.obs.ins.rebinds.value() > 0
+    assert scored == {r.rid: r.n_schedules for r in core.requests}
+
+
+def test_inline_drain_leaves_no_eq11_load(sim_env):
+    core = _inline_core(sim_env, n_workers=2, slice_len=16)
+    for r in generate_trace(8.0, 10.0, CODEFUSE, seed=3):
+        core.submit(r)
+    core.run_until_idle()
+    assert all(r.done for r in core.requests)
+    assert core.obs.ins.rebinds.value() > 0
+    assert {w for w in (ev["worker"] for ev in
+                        core.obs.audit.query(kind="withdraw"))} == {0, 1}
+    assert core.offloader.loads == pytest.approx({0: 0.0, 1: 0.0},
+                                                 abs=1e-9)
+
+
+def test_inline_cancel_in_withdrawn_batch_finalizes_once(sim_env):
+    core = _inline_core(sim_env, slice_len=8)
+    finals = []
+    core.add_observer(lambda kind, r: finals.append(r.rid)
+                      if kind == "final" else None)
+    core.submit(Request(rid=0, arrival=0.0, input_len=16, gen_len=20))
+    _submit_during_first_slice(
+        core, [Request(rid=1, arrival=0.0, input_len=16, gen_len=4)])
+    _step_until(core, lambda: core.workers[0].queue)  # [1] queued
+    assert core.cancel(1)
+    assert 1 not in finals  # it leaves when its batch is withdrawn
+    core.run_until_idle()
+    assert finals.count(1) == 1
+    r1 = core._by_rid[1]
+    assert r1.cancelled and not r1.done and r1.generated == 0
+    assert all(1 not in e[2] for e in core.batch_log)
+    assert core.requests[0].done
+    assert core.offloader.loads == pytest.approx({0: 0.0}, abs=1e-9)
+
+
+def test_real_backend_serves_mid_slice_submits_with_the_resident_rows(real_env):
+    """On the real backend, requests submitted while a slice runs (here:
+    as ``run_batch`` returns, before its completion event) ride the next
+    slice together with the rows that slice left unfinished."""
+    from repro.core.estimator import LatencyCoeffs, ServingTimeEstimator
+    arch, model, params, _ = real_env
+    # a fixed Eq. 3/4 estimator whose per-batch cost makes one batch of
+    # three strictly cheaper than any split (a fit on the CPU's timings
+    # may price either way)
+    est = ServingTimeEstimator(LatencyCoeffs(1e-6, 1e-4, 1e-6, 1e-3),
+                               LatencyCoeffs(1e-8, 1e-5, 1e-7, 1e-3))
+    scfg = ServingConfig(strategy="scls", backend="real", slice_len=8,
+                         max_gen=24, gamma=0.25, m_available=64e6,
+                         mem_bucket=8)
+    mem = scfg.memory_estimator(model.kv_bytes_per_token())
+    server = scfg.build_real(_make_engines(model, params, n=1), est, mem)
+    backend = server.core.backend
+    rng = np.random.default_rng(2)
+
+    def prompt(n):
+        return rng.integers(2, arch.vocab_size, size=n).astype(np.int32)
+
+    run_batch, late = backend.run_batch, []
+
+    def run_and_submit(wid, batch, prev):
+        ex = run_batch(wid, batch, prev)
+        if not late:
+            late.extend(server.submit(prompt(n), gen_len=4, max_gen=24)
+                        for n in (9, 14))
+        return ex
+
+    backend.run_batch = run_and_submit
+    first = server.submit(prompt(12), gen_len=12, max_gen=24)
+    server.drain()
+    assert first.done and all(h.done for h in late)
+    log = [e[2] for e in server.core.batch_log]
+    assert log[:2] == [[first.rid], sorted([first.rid]
+                                           + [h.rid for h in late])]
+    withdrawn = server.core.obs.audit.query(kind="withdraw")
+    assert server.core.obs.ins.rebinds.value() == len(withdrawn) >= 1
+    assert sorted(rid for w in withdrawn for rid in w["rids"]) \
+        == sorted(h.rid for h in late)
