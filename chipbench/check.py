@@ -2,8 +2,9 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the completed requests, drawn from the seed and always holding the one
-with the most served tokens, is run through the reference
-(``chipbench.reference``) over its prompt and its served tokens.  For
+with the most served tokens, is run through the configuration's plain
+reference (the module its ``check.reference`` names, by default
+``chipbench/reference.py``) over its prompt and its served tokens.  For
 each served token the reference gives the gap by which that token's
 logit lies below its best at that position; the number compared is the
 widest gap in the sample (``logit_gap``).  Every token was chosen
@@ -23,6 +24,7 @@ not ``correct``.  The program's own reading is kept beside it.
 from __future__ import annotations
 
 import math
+from types import ModuleType
 from typing import Callable, Dict, List
 
 import numpy as np
@@ -49,11 +51,10 @@ def sample(recs: List, seed: int, min_tokens: int, min_requests: int,
     return out
 
 
-def check(conf: Dict, params, recs: List, seed: int, log: Callable,
-          control: bool = False) -> Dict:
-    """The ``correct`` verdict and the numbers it compared."""
-    from chipbench import reference
-
+def check(conf: Dict, reference: ModuleType, params, recs: List, seed: int,
+          log: Callable, control: bool = False) -> Dict:
+    """The ``correct`` verdict and the numbers it compared; ``reference``
+    is the configuration's plain reference (``spec.Cell.reference``)."""
     ck = conf["check"]
     m = conf["model"]
     length_errors = sum(1 for q in recs

@@ -4,6 +4,17 @@ The counts are the model's own, not the program's: padded rows and
 padded positions do no useful work and are not counted, so a share of a
 peak computed from them cannot pass 100% unless the time is too short.
 ``m`` is a configuration's ``model`` section (``chipbench/configs``).
+
+A configuration names its counts by the path of a module in ``counts``
+(``chipbench/spec.py``); this one is the default.  Every such module
+gives the two whole-slice counts that ``chipbench/readers.py`` calls,
+each of one ``scls.slice`` record ``r`` (``harness.SliceLog``: per row
+its context ``ctx`` before the slice, whether it is ``fresh``, its
+``valid`` output tokens; the slice's ``reprefill`` tokens):
+
+- ``slice_flops(m, r)``: the slice's useful model FLOPs (``mfu``);
+- ``decode_slice_min_bytes(m, r)``: the least HBM bytes of the slice's
+  decode program (``decode_roofline``).
 """
 from __future__ import annotations
 
@@ -71,3 +82,28 @@ def decode_step_min_bytes(m: Mapping, contexts: Iterable[int],
     lookups = B * m["d_model"] * bytes_per_el
     kv = sum(contexts) * kv_bytes_per_token(m, bytes_per_el)
     return weights + lookups + kv
+
+
+def slice_flops(m: Mapping, r: Mapping) -> int:
+    """Useful model FLOPs of one slice: the first prefill of each new
+    row (its attention included), the tokens re-prefilled after an
+    eviction (their matmuls only, a lower bound), and one decode step per
+    valid output token at its context."""
+    total = 0
+    per_tok = 2 * m["n_layers"] * param_counts(m)["layer_matmul"]
+    total += prefill_flops(m, [c for c, f in zip(r["ctx"], r["fresh"]) if f])
+    total += r["reprefill"] * per_tok
+    for c, v in zip(r["ctx"], r["valid"]):
+        total += decode_step_flops(m, range(c + 1, c + v + 1))
+    return total
+
+
+def decode_slice_min_bytes(m: Mapping, r: Mapping) -> int:
+    """The least HBM bytes of one slice's decode program: per step, the
+    rows still producing valid tokens at their context then
+    (``decode_step_min_bytes``)."""
+    need = 0
+    for k in range(max(r["valid"], default=0)):
+        ctx = [c + k + 1 for c, v in zip(r["ctx"], r["valid"]) if v > k]
+        need += decode_step_min_bytes(m, ctx)
+    return need

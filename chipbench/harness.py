@@ -505,7 +505,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     conf, params = stack.conf, stack.params
     del stack, run
     gc.collect()
-    result.update(check.check(conf, params, recs, seed, log,
+    result.update(check.check(conf, cell.reference, params, recs, seed, log,
                               control=control))
     return result
 

@@ -10,7 +10,9 @@ each program's temporaries next to the weights and the pool.  A program
 that does not fit is refused here by the TPU compiler, as it would be on
 the chip.  The engine's own jitted functions are used, built around a
 pool of one page on the host; only their shapes are given the real
-sizes.
+sizes.  The pool is the arrays that engine holds, each with its page
+axis at the configuration's ``kv_pool_pages`` (plus the null page): their
+number, record width and type are the engine's own.
 """
 from __future__ import annotations
 
@@ -60,13 +62,14 @@ def main(argv=None) -> None:
     params = jax.tree_util.tree_map(
         lambda x: sds(x.shape, x.dtype),
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    pool = sds((cfg.n_layers, sv["kv_pool_pages"] + 1, pg,
-                cfg.n_kv_heads * cfg.head_dim), cfg.dtype)
+    pools = [sds(x.shape[:1] + (sv["kv_pool_pages"] + 1,) + x.shape[2:],
+                 x.dtype) for x in (eng._k_pages, eng._v_pages)]
     w_bytes = sum(x.size * x.dtype.itemsize
                   for x in jax.tree_util.tree_leaves(params))
-    p_bytes = 2 * pool.size * pool.dtype.itemsize
-    print(f"{args.config}: weights {w_bytes / GiB:.3f} GiB, pool K+V "
-          f"{p_bytes / GiB:.3f} GiB ({sv['kv_pool_pages']} pages)")
+    p_bytes = sum(x.size * x.dtype.itemsize for x in pools)
+    print(f"{args.config}: weights {w_bytes / GiB:.3f} GiB, pool "
+          f"{p_bytes / GiB:.3f} GiB ({sv['kv_pool_pages']} pages; "
+          f"{len(pools)} arrays of {pools[0].shape} {pools[0].dtype})")
     max_len = conf["max_effective_input"]
     pre = grid.prefill_shapes(sv, max_len)
     dec = grid.decode_shapes(sv, max_len)
@@ -84,11 +87,11 @@ def main(argv=None) -> None:
                 if kind == "prefill":
                     fn = eng._prefill_paged
                     low = fn.lower(params, sds((b, x), i32), sds((b,), i32),
-                                   pool, pool,
+                                   *pools,
                                    sds((b, grid.ceil_div(x, pg)), i32))
                 else:
                     fn = eng._get_compiled_paged(S)
-                    low = fn.lower(params, pool, pool, sds((b, x), i32),
+                    low = fn.lower(params, *pools, sds((b, x), i32),
                                    sds((b, x * pg), i32), sds((b,), i32),
                                    sds((b,), i32), sds((b,), i32))
                 m = low.compile().memory_analysis()

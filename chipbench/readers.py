@@ -7,7 +7,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from chipbench import flops, stats
+from chipbench import stats
 
 #: the decode-slice program, by its jitted name
 #: (``StaticEngine._serve_paged_fn``'s ``serve``)
@@ -43,36 +43,22 @@ def queue_wait_p50_ms(run) -> Optional[float]:
     return stats.percentile(waits, 50)
 
 
-def _slice_flops(m, r) -> int:
-    """Useful model FLOPs of one slice: the first prefill of each new
-    row (its attention included), the tokens re-prefilled after an
-    eviction (their matmuls only, a lower bound), and one decode step per
-    valid output token at its context."""
-    total = 0
-    per_tok = 2 * m["n_layers"] * flops.param_counts(m)["layer_matmul"]
-    total += flops.prefill_flops(
-        m, [c for c, f in zip(r["ctx"], r["fresh"]) if f])
-    total += r["reprefill"] * per_tok
-    for c, v in zip(r["ctx"], r["valid"]):
-        total += flops.decode_step_flops(m, range(c + 1, c + v + 1))
-    return total
-
-
 def mfu(run) -> Optional[float]:
-    """Model FLOPs of the slices inside the traced window over the
-    window times the chip's bf16 peak (percent)."""
+    """Model FLOPs of the slices inside the traced window (the
+    configuration's ``counts``) over the window times the chip's bf16
+    peak (percent)."""
     s = _slices(run)
     if not s or run.trace is None or run.peaks is None:
         return None
-    work = sum(_slice_flops(run.model, r) for r in s)
+    work = sum(run.cell.counts.slice_flops(run.model, r) for r in s)
     return 100.0 * work / (run.trace["window_s"] * run.peaks["bf16_flops"])
 
 
 def decode_roofline(run) -> Optional[float]:
     """The decode-slice program's least HBM time over its device time
-    (percent; HBM bounds a decode step): per slice, every valid row's
-    steps read the weights once per step and each row's resident K/V,
-    over the device time of that slice's ``jit_serve`` run."""
+    (percent; HBM bounds a decode step): per slice, its least bytes (the
+    configuration's ``counts``) over the device time of that slice's
+    ``jit_serve`` run."""
     s = _slices(run)
     if not s or run.trace is None or run.peaks is None:
         return None
@@ -87,9 +73,7 @@ def decode_roofline(run) -> Optional[float]:
         if len(mine) != 1:
             continue
         dev_s += mine[0][1] - mine[0][0]
-        for k in range(max(r["valid"], default=0)):
-            ctx = [c + k + 1 for c, v in zip(r["ctx"], r["valid"]) if v > k]
-            need += flops.decode_step_min_bytes(run.model, ctx)
+        need += run.cell.counts.decode_slice_min_bytes(run.model, r)
     if dev_s <= 0:
         return None
     return 100.0 * need / run.peaks["hbm_bytes_per_s"] / dev_s

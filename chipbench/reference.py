@@ -2,9 +2,30 @@
 Mistral): full-sequence causal forward in float32 at ``highest`` matmul
 precision, one layer at a time, in straightforward ``jax.numpy``.
 
-It imports nothing of the program.  It reads the benchmark's own weights
-(``chipbench.weights``) by the names of the program's parameter tree:
-``embed.table``; per layer ``attn.{wq,wk,wv,wo}.{w,b}``,
+A configuration names its plain reference by the path of a module in
+``check.reference`` (``chipbench/spec.py``); this one is the default.
+Every such module keeps to one contract:
+
+- ``score(params, m, seq, first, alt=None, precision="f32")`` scores the
+  tokens ``seq[first:]`` against the model's predictions at positions
+  ``first - 1 .. len(seq) - 2`` and returns a dict of NumPy arrays with
+  one entry per scored token: ``best``, the highest logit there; ``top``,
+  the token that has it; ``served``, the logit of the token in ``seq``;
+  ``alt``, the logit of ``alt``'s token there (``alt`` gives one token
+  per scored position, or is ``None``).  ``m`` is the configuration's
+  ``model`` section.  ``chipbench/check.py`` compares ``best - served``.
+- ``precision="f32"`` computes in float32 with every matmul at
+  ``jax.lax.Precision.HIGHEST``; ``precision="fp8"`` is the control, the
+  same forward with every matmul's inputs rounded to the nearest
+  precision below the configuration's (float8 e4m3 for bfloat16), which
+  has to come out not ``correct``.
+- It imports nothing of the program and takes nothing the program made.
+  It reads the benchmark's own weights (``chipbench/weights.py``, made
+  from the seed) by the names of the program's parameter tree.
+- It fits on the chip beside nothing else: the check runs it once the
+  program's state is freed, one layer or one block of rows at a time.
+
+This module reads ``embed.table``; per layer ``attn.{wq,wk,wv,wo}.{w,b}``,
 ``mlp.{gate,up,down}.w``, ``ln_attn``, ``ln_mlp``; ``ln_f``;
 ``unembed.w``.  Norm weights are stored as the RMSNorm scale minus one.
 

@@ -3,7 +3,9 @@ the dense family at 2 layers of width 64), past the look for a chip: a
 sound run is ``correct``; the same run is not with the served tokens
 altered where the engine produces them, nor with decode steps that leave
 the K/V pool as they found it; and with the float8 control put in the
-program's place, the same verdict comes out not ``correct``."""
+program's place, the same verdict comes out not ``correct``.  A
+configuration that names a plain reference of its own (``tiny-planted``,
+a new file with a planted fault) is judged by that reference."""
 import time
 
 import numpy as np
@@ -14,6 +16,8 @@ from chipbench.harness import CompileClock, run_cell
 
 ROOT = spec.HERE / "tests" / "data"
 BENCH = {"workloads": [{"name": "tiny.cell", "config": "tiny",
+                        "traffic": "tiny", "chips": 1},
+                       {"name": "tiny-planted.cell", "config": "tiny-planted",
                         "traffic": "tiny", "chips": 1}],
          "end_to_end": [{"name": n, "unit": "x"} for n in
                         ("out_tok_s", "ttft_p95_ms", "norm_lat_p95_ms",
@@ -27,8 +31,8 @@ def compiles():
     return CompileClock()
 
 
-def _run(compiles, control=False):
-    cell = spec.cell("tiny.cell", BENCH, root=ROOT)
+def _run(compiles, control=False, name="tiny.cell"):
+    cell = spec.cell(name, BENCH, root=ROOT)
     return run_cell(cell, SEED, 4.0, False, time.perf_counter(), compiles,
                     require_tpu=False, log=lambda *a: None,
                     control=control)
@@ -96,3 +100,14 @@ def test_float8_control_fails_the_limit(compiles):
     assert res["program_gap"] <= limit
     assert res["check"]["logit_gap"]["value"] > limit
     assert np.isfinite(res["check"]["logit_gap"]["value"])
+
+
+def test_configuration_names_its_own_reference(compiles):
+    """The same run, judged by the reference that its configuration
+    names: that reference puts every position's best 1.0 higher, so the
+    widest gap passes the limit although the program is sound."""
+    res = _run(compiles, name="tiny-planted.cell")
+    assert res["correct"] is False
+    assert res["check"]["length_errors"]["value"] == 0
+    assert res["check"]["logit_gap"]["value"] >= 1.0 > \
+        res["check"]["logit_gap"]["limit"]

@@ -2,6 +2,7 @@
 names, units, metric keys, the cells each metric is read in."""
 import json
 import re
+import shutil
 
 import pytest
 
@@ -63,3 +64,50 @@ def test_config_files(c):
         else:
             assert pub[src] == m[k], (k, src)
     assert m["head_dim"] * m["n_heads"] == pub["hidden_size"]
+
+
+def test_modules_default_to_the_dense_ones():
+    """A configuration that names no reference or counts gets the dense
+    ones; one that names its own gets those files."""
+    dense = {"reference": spec.HERE / "reference.py",
+             "counts": spec.HERE / "flops.py"}
+    for w in BENCH["workloads"]:
+        cell = spec.cell(w["name"], BENCH)
+        assert cell.reference.__file__ == str(dense["reference"])
+        assert cell.counts.__file__ == str(dense["counts"])
+    data = spec.HERE / "tests" / "data"
+    bench = {"workloads": [{"name": c, "config": c, "traffic": "tiny",
+                            "chips": 1} for c in ("tiny", "tiny-planted")],
+             "end_to_end": [], "per_layer": []}
+    tiny = spec.cell("tiny", bench, root=data)
+    assert tiny.reference.__file__ == str(dense["reference"])
+    assert tiny.counts.__file__ == str(dense["counts"])
+    own = spec.cell("tiny-planted", bench, root=data)
+    assert own.reference.__file__ == str(data / "reference_gap_plus_one.py")
+    assert own.counts.__file__ == str(data / "counts_by_row.py")
+
+
+@pytest.mark.parametrize("key,path", [
+    ("check.reference", "chipbench/tests/data/no_such_reference.py"),
+    ("counts", "chipbench/no_such_counts.py"),
+    ("counts", "../outside.py"),
+    ("counts", "/etc/hostname"),
+])
+def test_missing_module_fails_at_cell(tmp_path, key, path):
+    """A configuration naming a module that is not a file of the checkout
+    fails when its cell is loaded, naming the key and the path."""
+    data = spec.HERE / "tests" / "data"
+    conf = json.loads((data / "configs" / "tiny.json").read_text())
+    if key == "counts":
+        conf["counts"] = path
+    else:
+        conf["check"]["reference"] = path
+    (tmp_path / "configs").mkdir()
+    (tmp_path / "configs" / "bad.json").write_text(json.dumps(conf))
+    shutil.copytree(data / "traffic", tmp_path / "traffic")
+    bench = {"workloads": [{"name": "bad.cell", "config": "bad",
+                            "traffic": "tiny", "chips": 1}],
+             "end_to_end": [], "per_layer": []}
+    with pytest.raises(KeyError) as e:
+        spec.cell("bad.cell", bench, root=tmp_path)
+    assert key in str(e.value) and path in str(e.value)
