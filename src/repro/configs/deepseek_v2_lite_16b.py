@@ -1,7 +1,11 @@
 """deepseek-v2-lite-16b [moe] — MLA kv_lora=512, 2 shared + 64 routed top-6
 [arXiv:2405.04434].  The source paper's Lite config is 64 routed experts
 (the assignment line's "160 routed" belongs to the full V2); layer 0 is a
-dense MLP (d_ff 10944), experts use d_ff 1408.
+dense MLP (d_ff 10944), experts use d_ff 1408.  As published
+(huggingface.co/deepseek-ai/DeepSeek-V2-Lite config.json): the router is a
+float32 softmax whose top-6 weights are not renormalised
+(``norm_topk_prob: false``), and the rotary part uses YaRN (factor 40 over
+4096 original positions, beta 32/1, mscale = mscale_all_dim = 0.707).
 """
 import jax.numpy as jnp
 
@@ -12,9 +16,12 @@ CONFIG = ModelConfig(
     n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
     d_ff=10944, vocab_size=102400,
     n_experts=64, n_shared_experts=2, top_k=6, d_ff_expert=1408,
-    capacity_factor=1.25, first_dense_layers=1,
+    capacity_factor=1.25, first_dense_layers=1, norm_topk_prob=False,
     use_mla=True, kv_lora_rank=512, qk_nope_head_dim=128,
     qk_rope_head_dim=64, v_head_dim=128,
+    rope_theta=10000.0, rope_scaling_factor=40.0, rope_original_max_pos=4096,
+    rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=0.707,
+    rope_mscale_all_dim=0.707,
     tie_embeddings=False, act="silu", dtype=jnp.bfloat16,
 )
 

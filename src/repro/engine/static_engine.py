@@ -115,9 +115,11 @@ class StaticEngine:
         if kv_layout == "paged":
             from repro.kvcache import PageAllocator, PrefixIndex  # deferred import cycle
             cfg = model.cfg
-            if cfg.family != "dense":
-                raise ValueError("persistent paged StaticEngine: dense "
-                                 f"family only, got {cfg.family!r}")
+            if model.paged_pages is None:
+                raise ValueError("persistent paged StaticEngine: no paged "
+                                 f"entry points for {cfg.name!r} (family "
+                                 f"{cfg.family!r}; dense and MoE with "
+                                 "latent attention have them)")
             if self.extra_inputs:
                 raise ValueError("persistent paged StaticEngine does not "
                                  "take frontend extra_inputs")
@@ -131,46 +133,46 @@ class StaticEngine:
             self.allocator = PageAllocator(kv_pool_tokens // page_tokens,
                                            page_tokens)
             P = self.allocator.n_pages + 1  # + null page 0
-            # a slot's K (and V) is stored lane-dense as one Hkv·D record
-            # (kernels.ref.pool_view): the TPU tiles the last two dims by
-            # (8, 128), so a minor dim of head_dim = 64 fills half a
-            # tile's lanes; Hkv·D (512 for llama3.2-1b) tiles exactly
-            shape = (cfg.n_layers, P, page_tokens,
-                     cfg.n_kv_heads * cfg.head_dim)
+            # a slot's record is stored lane-dense (kernels.ref.pool_view):
+            # K and V as one Hkv·D record each (dense; the TPU tiles the
+            # last two dims by (8, 128), so a minor dim of head_dim = 64
+            # fills half a tile's lanes, Hkv·D = 512 for llama3.2-1b tiles
+            # exactly), or c_kv and the rotary key (latent attention,
+            # ``models.mla.page_shape``)
+            page_k, page_v = model.paged_pages(page_tokens)
             # filled on the device itself: jnp.zeros(device=...) fills on
             # the default device and copies, so every engine's pool would
             # pass through chip 0
             with jax.default_device(device):
-                self._k_pages = jnp.zeros(shape, cfg.dtype)
-                self._v_pages = jnp.zeros(shape, cfg.dtype)
+                self._k_pages = jnp.zeros((cfg.n_layers, P) + page_k,
+                                          cfg.dtype)
+                self._v_pages = jnp.zeros((cfg.n_layers, P) + page_v,
+                                          cfg.dtype)
             self._resident: Dict[int, _Resident] = {}
             self._prefix = PrefixIndex(page_tokens)
             self._stamp = 0
             self.n_evictions = 0
-            from repro.models import transformer as _tfm
             from repro.kvcache.paged import PagedKVCache as _PKV
+
+            def _cache(tokens, k_pages, v_pages, block_table):
+                W = block_table.shape[1] * page_tokens
+                return _PKV(k_pages, v_pages, block_table,
+                            jnp.full((tokens.shape[0], W), -1, jnp.int32),
+                            jnp.zeros((tokens.shape[0],), jnp.int32))
 
             def _prefill_paged(params, tokens, lengths, k_pages, v_pages,
                                block_table):
-                W = block_table.shape[1] * page_tokens
-                cache = _PKV(k_pages, v_pages, block_table,
-                             jnp.full((tokens.shape[0], W), -1, jnp.int32),
-                             jnp.zeros((tokens.shape[0],), jnp.int32))
-                logits, cache = _tfm.prefill_paged(params, cfg, tokens,
-                                                   lengths, cache,
-                                                   attn_impl=attn_impl)
-                return greedy(logits), cache.k_pages, cache.v_pages
+                logits, cache, counts = model.prefill_paged(
+                    params, tokens, lengths,
+                    _cache(tokens, k_pages, v_pages, block_table), attn_impl)
+                return greedy(logits), cache.k_pages, cache.v_pages, counts
 
             def _prefill_tail(params, tokens, start, lengths, k_pages,
                               v_pages, block_table):
-                W = block_table.shape[1] * page_tokens
-                cache = _PKV(k_pages, v_pages, block_table,
-                             jnp.full((tokens.shape[0], W), -1, jnp.int32),
-                             jnp.zeros((tokens.shape[0],), jnp.int32))
-                logits, cache = _tfm.prefill_tail_paged(params, cfg, tokens,
-                                                        start, lengths, cache,
-                                                        attn_impl=attn_impl)
-                return greedy(logits), cache.k_pages, cache.v_pages
+                logits, cache, counts = model.prefill_tail_paged(
+                    params, tokens, start, lengths,
+                    _cache(tokens, k_pages, v_pages, block_table), attn_impl)
+                return greedy(logits), cache.k_pages, cache.v_pages, counts
 
             # donate the pool buffers so XLA updates them in place (the
             # pool is sized to most of HBM; without donation every call
@@ -229,8 +231,7 @@ class StaticEngine:
     # ------------------------------------------------------------------
     def _serve_paged_fn(self, slice_len: int):
         from repro.kvcache.paged import PagedKVCache
-        from repro.models import transformer as tfm
-        cfg, eos = self.model.cfg, self.eos_id
+        model, eos = self.model, self.eos_id
         attn_impl = self.attn_impl
         # pool buffers donated in place, as in _prefill_paged
         donate = (1, 2) if self._donate else ()
@@ -243,29 +244,34 @@ class StaticEngine:
                                  row_len)
 
             def cond(state):
-                step, _, _, done, _ = state
+                step, _, _, done, _, _ = state
                 return (step < slice_len) & ~jnp.all(done)
 
             def body(state):
-                step, cur, cache, done, out = state
+                step, cur, cache, done, out, counts = state
                 out = jax.lax.dynamic_update_slice_in_dim(
                     out, cur[:, None], step, axis=1)
                 gen_count = step + 1
                 done = done | (cur == eos) | (gen_count >= forced)
                 q_pos = row_len + step  # compact layout: slot == position
-                logits, cache = tfm.decode_step_paged(params, cfg, cache,
-                                                      cur, q_pos, q_pos,
-                                                      attn_impl=attn_impl)
+                # rows done (padding, or finished) count no work
+                logits, cache, c = model.decode_step_paged(
+                    params, cache, cur, q_pos, q_pos, done, attn_impl)
                 with jax.named_scope("sampling"):
                     nxt = greedy(logits)
-                return step + 1, nxt, cache, done, out
+                return (step + 1, nxt, cache, done, out,
+                        jax.tree.map(jnp.add, counts, c))
 
             out = jnp.full((B, slice_len), -1, jnp.int32)
             done0 = jnp.zeros((B,), bool)
-            step, nxt, cache, done, out = jax.lax.while_loop(
+            counts0 = {k: jnp.zeros((), jnp.int32)
+                       for k in model.decode_counts}
+            step, nxt, cache, done, out, counts = jax.lax.while_loop(
                 cond, body,
-                (jnp.asarray(0, jnp.int32), first_tok, cache, done0, out))
-            return out, step, done, nxt, cache.k_pages, cache.v_pages
+                (jnp.asarray(0, jnp.int32), first_tok, cache, done0, out,
+                 counts0))
+            return (out, step, done, nxt, cache.k_pages, cache.v_pages,
+                    counts)
 
         return serve
 
@@ -398,7 +404,7 @@ class StaticEngine:
             L_pre = bucket_len(max_eff, self.len_bucket)
             Bp = _pow2_bucket(len(pre_idx))
             with tr.span("engine.prefill", rows=len(pre_idx), padded=Bp,
-                         length=L_pre):
+                         length=L_pre) as sp:
                 toks = np.full((Bp, L_pre), self.pad_id, np.int32)
                 lens = np.ones((Bp,), np.int32)
                 nb_p = -(-L_pre // pg)
@@ -411,10 +417,12 @@ class StaticEngine:
                     btp[s, :min(len(pages), nb_p)] = pages[:nb_p]
                     if prevs[i]:  # re-prefill beyond the first (§3.3)
                         reprefill += len(e)
-                tok0, self._k_pages, self._v_pages = self._prefill_paged(
-                    self.params, self._put(toks), self._put(lens),
-                    self._k_pages, self._v_pages, self._put(btp))
+                tok0, self._k_pages, self._v_pages, counts = \
+                    self._prefill_paged(
+                        self.params, self._put(toks), self._put(lens),
+                        self._k_pages, self._v_pages, self._put(btp))
                 tok0 = np.asarray(tok0)  # host transfer: blocks on stage A
+                sp.set(**{k: int(v) for k, v in counts.items()})
                 for s, i in enumerate(pre_idx):
                     first[i] = int(tok0[s])
                     row_len[i] = len(eff[i])
@@ -426,7 +434,7 @@ class StaticEngine:
             T_t = bucket_len(max_tail, self.len_bucket)
             Bt = _pow2_bucket(len(tail_idx))
             with tr.span("engine.prefill_tail", rows=len(tail_idx),
-                         padded=Bt, length=T_t):
+                         padded=Bt, length=T_t) as sp:
                 toks_t = np.full((Bt, T_t), self.pad_id, np.int32)
                 start_t = np.zeros((Bt,), np.int32)
                 lens_t = np.zeros((Bt,), np.int32)
@@ -442,12 +450,13 @@ class StaticEngine:
                     btt[s, :min(len(pages), nb_t)] = pages[:nb_t]
                     if prevs[i]:  # only the tail re-runs on a reschedule
                         reprefill += len(e) - st
-                tokt, self._k_pages, self._v_pages = \
+                tokt, self._k_pages, self._v_pages, counts = \
                     self._prefill_tail_paged(
                         self.params, self._put(toks_t), self._put(start_t),
                         self._put(lens_t), self._k_pages, self._v_pages,
                         self._put(btt))
                 tokt = np.asarray(tokt)  # host transfer: blocks on stage A'
+                sp.set(**{k: int(v) for k, v in counts.items()})
                 for s, i in enumerate(tail_idx):
                     first[i] = int(tokt[s])
                     row_len[i] = len(eff[i])
@@ -476,7 +485,7 @@ class StaticEngine:
                 [first, np.full((B - B_raw,), self.pad_id, np.int32)])
             forced = self._forced_array(forced_gen_lens, B, B_raw)
             fn = self._get_compiled_paged(slice_len)
-            out, steps, done, nxt, kp, vp = fn(
+            out, steps, done, nxt, kp, vp, counts = fn(
                 self.params, self._k_pages, self._v_pages, self._put(bt),
                 self._put(slot_pos),
                 self._put(np.asarray(lens_full, np.int32)),
@@ -486,7 +495,8 @@ class StaticEngine:
             nxt = np.asarray(nxt)
             wall = time.perf_counter() - t0
             steps = int(steps)
-            ph.set(blocks=nb, steps=steps)
+            counts = {k: int(v) for k, v in counts.items()}
+            ph.set(blocks=nb, steps=steps, **counts)
 
         # --- retention: trim every row to its resident tokens; pages are
         # freed only via release_request (finish/cancel) or eviction
@@ -514,7 +524,8 @@ class StaticEngine:
                            reprefill_tokens=reprefill,
                            prefill_time=t_prefill,
                            prefix_hit_tokens=prefix_hit,
-                           shared_blocks=shared_blocks)
+                           shared_blocks=shared_blocks,
+                           expert_reads=counts.get("expert_reads", 0))
 
     def _plan(self, rids: Sequence[int], eff: List[np.ndarray],
               slice_len: int) -> Tuple[List[bool], Dict[int, int], int]:
@@ -686,7 +697,8 @@ class ServeResult:
                  batch_input_len: int, batch_size: int, early_return: bool,
                  reprefill_tokens: int = 0,
                  prefill_time: Optional[float] = None,
-                 prefix_hit_tokens: int = 0, shared_blocks: int = 0):
+                 prefix_hit_tokens: int = 0, shared_blocks: int = 0,
+                 expert_reads: int = 0):
         self.results = results
         self.steps = steps
         self.wall_time = wall_time
@@ -709,3 +721,7 @@ class ServeResult:
         #: number of pages those joins took references on
         self.prefix_hit_tokens = prefix_hit_tokens
         self.shared_blocks = shared_blocks
+        #: routed experts the decode program read: per step and MoE
+        #: layer, the held experts at least one live row was routed to
+        #: (0 for a model without routed experts)
+        self.expert_reads = expert_reads

@@ -112,10 +112,11 @@ def build_server(cfg: ServingConfig) -> tuple[SliceServer, int]:
     if cfg.kv_retain == "request":
         # persistent paged storage: each engine owns the page pool the
         # scheduler budgets, and prefix pages survive across slices
-        if arch.family != "dense":
+        if model.paged_pages is None:
             raise SystemExit(f"--kv-retain request drives the persistent "
-                             f"paged StaticEngine (dense family only); "
-                             f"{cfg.arch} is {arch.family}")
+                             f"paged StaticEngine (dense, and MoE with "
+                             f"latent attention); {cfg.arch} is "
+                             f"{arch.family}")
         engines = [StaticEngine(model, params, eos_id=1, len_bucket=8,
                                 kv_layout="paged",
                                 page_tokens=cfg.page_tokens,

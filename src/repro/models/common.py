@@ -8,11 +8,13 @@ traced HLO is O(1) in depth (critical for the 512-device dry-run compiles).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 
@@ -34,6 +36,14 @@ class ModelConfig:
     d_ff: int = 512
     vocab_size: int = 1024
     rope_theta: float = 10000.0
+    # YaRN rotary scaling (arXiv:2309.00071, deepseek-v2's rope_scaling);
+    # a factor of 1 is plain RoPE
+    rope_scaling_factor: float = 1.0
+    rope_original_max_pos: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     norm_eps: float = 1e-6
     act: str = "silu"  # silu (SwiGLU) | gelu (GeGLU)
     qkv_bias: bool = False  # qwen1.5
@@ -52,6 +62,14 @@ class ModelConfig:
     capacity_factor: float = 1.25
     first_dense_layers: int = 0  # deepseek-v2: layer 0 is a dense MLP
     router_aux_coef: float = 0.01
+    # renormalise the top-k router weights to sum to 1 (mixtral); deepseek-v2
+    # keeps the softmax probabilities as they are
+    norm_topk_prob: bool = True
+    # expert parallelism: this layer holds experts [expert_offset,
+    # expert_offset + n_experts_held) of the n_experts the router scores
+    # (0 = all of them); tokens routed elsewhere get nothing from here
+    n_experts_held: int = 0
+    expert_offset: int = 0
     # --- MLA (deepseek-v2) ---
     use_mla: bool = False
     kv_lora_rank: int = 512
@@ -82,6 +100,10 @@ class ModelConfig:
     @property
     def n_rep(self) -> int:
         return self.n_heads // max(self.n_kv_heads, 1)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def d_lru(self) -> int:
@@ -165,15 +187,67 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., T, H, D); positions: broadcastable to (..., T)."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               freqs: Optional[jnp.ndarray] = None,
+               mult: float = 1.0) -> jnp.ndarray:
+    """x: (..., T, H, D); positions: broadcastable to (..., T).  ``freqs``
+    (D/2,) overrides the plain inverse frequencies (YaRN) and ``mult``
+    scales cos and sin."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # (D/2,)
+    if freqs is None:
+        freqs = rope_freqs(d, theta)  # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., T, D/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]  # (...,T,1,D/2)
+    if mult != 1.0:
+        cos, sin = cos * mult, sin * mult
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_dim(n_rot: float, dim: int, theta: float, max_pos: int) -> float:
+    """The rotary pair index whose wavelength fits ``n_rot`` turns in
+    ``max_pos`` positions."""
+    return (dim * math.log(max_pos / (n_rot * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def yarn_freqs(dim: int, cfg: ModelConfig) -> jnp.ndarray:
+    """Inverse rotary frequencies (dim/2,) under YaRN: pairs below the
+    ``beta_fast`` correction dim keep theta^(-2i/dim), pairs above the
+    ``beta_slow`` one are divided by the factor, and a linear ramp
+    blends the pairs in between (deepseek-v2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    base = 1.0 / (cfg.rope_theta ** (np.arange(0, dim, 2) / dim))
+    f = cfg.rope_scaling_factor
+    if f <= 1:
+        return jnp.asarray(base, jnp.float32)
+    lo = max(math.floor(_yarn_dim(cfg.rope_beta_fast, dim, cfg.rope_theta,
+                                  cfg.rope_original_max_pos)), 0)
+    hi = min(math.ceil(_yarn_dim(cfg.rope_beta_slow, dim, cfg.rope_theta,
+                                 cfg.rope_original_max_pos)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    return jnp.asarray(base / f * ramp + base * (1.0 - ramp), jnp.float32)
+
+
+def yarn_rope_mult(cfg: ModelConfig) -> float:
+    """The factor on YaRN's cos and sin: mscale over mscale_all_dim."""
+    f = cfg.rope_scaling_factor
+    return (yarn_mscale(f, cfg.rope_mscale)
+            / yarn_mscale(f, cfg.rope_mscale_all_dim))
+
+
+def yarn_softmax_factor(cfg: ModelConfig) -> float:
+    """The factor on the attention softmax scale: mscale(all dims)^2 (1
+    without YaRN, or with ``rope_mscale_all_dim`` 0)."""
+    return yarn_mscale(cfg.rope_scaling_factor, cfg.rope_mscale_all_dim) ** 2
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,22 @@ family themselves:
   decode_step(params, cache, tokens, step)       -> (logits, cache)
   kv_bytes_per_token(n_model_shards)             -> float  (Δ in Eq. 5)
 
+and, for the families the persistent paged engine serves (dense, and MoE
+with MLA), three paged entry points over a ``kvcache.paged.PagedKVCache``
+whose two stacked pools (L, P) + ``paged_pages(page_tokens)`` hold K and V
+(dense) or c_kv and the rotary key (MLA):
+
+  prefill_paged(params, tokens, lengths, cache, attn_impl)
+                                                 -> (last_logits, cache, counts)
+  prefill_tail_paged(params, tokens, start, lengths, cache, attn_impl)
+                                                 -> (last_logits, cache, counts)
+  decode_step_paged(params, cache, tokens, q_pos, slots, done, attn_impl)
+                                                 -> (logits, cache, counts)
+
+``counts`` is a dict of int32 scalars the engine puts on its spans (empty
+for dense; ``decode_counts`` names the decode step's).  Other families
+leave them None and the paged engine refuses them.
+
 ``batch`` is a dict: tokens (B,T) int32, lengths (B,) int32, and optionally
 labels / loss_mask (train), src_embeds or prefix_embeds (audio / vlm stubs).
 ``window_override`` lets the long_500k shape force a sliding window on
@@ -17,12 +33,12 @@ otherwise full-attention archs (DESIGN.md §5).
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.models import encdec, mamba2, moe, rglru, transformer
+from repro.models import encdec, mamba2, mla, moe, rglru, transformer
 from repro.models.common import ModelConfig, softmax_xent
 
 
@@ -33,6 +49,14 @@ class Model(NamedTuple):
     prefill: Callable
     decode_step: Callable
     kv_bytes_per_token: Callable
+    prefill_paged: Optional[Callable] = None
+    prefill_tail_paged: Optional[Callable] = None
+    decode_step_paged: Optional[Callable] = None
+    #: page_tokens -> the page shapes (rows, lanes) of the paged
+    #: engine's two pools
+    paged_pages: Optional[Callable] = None
+    #: names of the counts ``decode_step_paged`` returns
+    decode_counts: Tuple[str, ...] = ()
 
 
 def _dtype_bytes(cfg: ModelConfig) -> int:
@@ -72,8 +96,32 @@ def _dense(cfg: ModelConfig) -> Model:
         shard = min(n_shards, cfg.n_kv_heads)  # MQA replicates KV on model axis
         return per_tok / shard
 
+    def prefill_paged(params, tokens, lengths, cache, attn_impl="unfused"):
+        logits, cache = transformer.prefill_paged(params, cfg, tokens, lengths,
+                                                  cache, attn_impl=attn_impl)
+        return logits, cache, {}
+
+    def prefill_tail_paged(params, tokens, start, lengths, cache,
+                           attn_impl="unfused"):
+        logits, cache = transformer.prefill_tail_paged(
+            params, cfg, tokens, start, lengths, cache, attn_impl=attn_impl)
+        return logits, cache, {}
+
+    def decode_step_paged(params, cache, tokens, q_pos, slots, done,
+                          attn_impl="unfused"):
+        logits, cache = transformer.decode_step_paged(
+            params, cfg, cache, tokens, q_pos, slots, attn_impl=attn_impl)
+        return logits, cache, {}
+
+    paged = {}
+    if cfg.family == "dense":  # token-only: no frontend embeddings
+        rec = cfg.n_kv_heads * cfg.head_dim
+        paged = dict(prefill_paged=prefill_paged,
+                     prefill_tail_paged=prefill_tail_paged,
+                     decode_step_paged=decode_step_paged,
+                     paged_pages=lambda pg: ((pg, rec), (pg, rec)))
     return Model(cfg, lambda k: transformer.init(k, cfg), loss, prefill,
-                 decode_step, kv_bytes)
+                 decode_step, kv_bytes, **paged)
 
 
 def _ssm(cfg: ModelConfig) -> Model:
@@ -138,8 +186,36 @@ def _moe(cfg: ModelConfig) -> Model:
         per_tok = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * b
         return per_tok / min(n_shards, cfg.n_kv_heads)
 
+    def unfused(attn_impl):
+        if attn_impl != "unfused":
+            raise ValueError(f"{cfg.name}: latent attention has no "
+                             f"{attn_impl!r} kernels")
+
+    def prefill_paged(params, tokens, lengths, cache, attn_impl="unfused"):
+        unfused(attn_impl)
+        return moe.prefill_paged(params, cfg, tokens, lengths, cache)
+
+    def prefill_tail_paged(params, tokens, start, lengths, cache,
+                           attn_impl="unfused"):
+        unfused(attn_impl)
+        return moe.prefill_tail_paged(params, cfg, tokens, start, lengths,
+                                      cache)
+
+    def decode_step_paged(params, cache, tokens, q_pos, slots, done,
+                          attn_impl="unfused"):
+        unfused(attn_impl)
+        return moe.decode_step_paged(params, cfg, cache, tokens, q_pos,
+                                     slots, done)
+
+    paged = {}
+    if cfg.use_mla:
+        paged = dict(prefill_paged=prefill_paged,
+                     prefill_tail_paged=prefill_tail_paged,
+                     decode_step_paged=decode_step_paged,
+                     paged_pages=lambda pg: mla.paged_pages(cfg, pg),
+                     decode_counts=("expert_reads", "expert_rows"))
     return Model(cfg, lambda k: moe.init(k, cfg), loss, prefill,
-                 decode_step, kv_bytes)
+                 decode_step, kv_bytes, **paged)
 
 
 def _encdec(cfg: ModelConfig) -> Model:

@@ -38,7 +38,8 @@ Metric catalog (all ``scls_`` namespaced; catalog with units in
               ``scls_admission_total{action,reason}``,
               ``scls_reprefill_tokens_total``,
               ``scls_prefix_hit_tokens_total``, ``scls_compiles_total``,
-              ``scls_dispatch_rebinds_total``
+              ``scls_dispatch_rebinds_total``,
+              ``scls_moe_expert_reads_total``
   gauges      ``scls_queue_depth``, ``scls_in_flight_slices``,
               ``scls_kv_free_pages``, ``scls_kv_retained_blocks``,
               ``scls_kv_evictions``, ``scls_kv_shared_blocks``
@@ -154,6 +155,11 @@ class ServingInstruments:
             "Unstarted batches withdrawn from a worker's queue when it "
             "freed and formed again with the rows its slice returned "
             "(backends that run a slice inline)")
+        self.expert_reads = registry.counter(
+            "scls_moe_expert_reads_total",
+            "Routed experts the decode program read: per decode step and "
+            "MoE layer, the held experts at least one live row was routed "
+            "to (real backend, models with routed experts)")
         self.queue_depth = registry.gauge(
             "scls_queue_depth",
             "Requests waiting to be dispatched (pool + worker queues)")
@@ -390,13 +396,15 @@ class Observability:
 
     def on_slice_done(self, core: "SchedulerCore", wid: int, b: "Batch",
                       reprefill_tokens: int, prefix_hit_tokens: int = 0,
-                      shared_blocks: int = 0) -> None:
+                      shared_blocks: int = 0, expert_reads: int = 0) -> None:
         ins = self.ins
         if ins is not None:
             ins.reprefill.inc(reprefill_tokens)
             ins.reprefill_hist.observe(reprefill_tokens)
             if prefix_hit_tokens:
                 ins.prefix_hit.inc(prefix_hit_tokens)
+            if expert_reads:
+                ins.expert_reads.inc(expert_reads)
             if self.host_clock:
                 # the first token reaches the client at the end of the
                 # request's first slice (host clock, stamped by the backend)

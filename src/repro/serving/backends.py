@@ -91,6 +91,9 @@ class BatchExecution:
     #: host seconds of the whole dispatch (the real backend's ``slice``
     #: program span: planning, device stages and retention); None on sim
     host_seconds: Optional[float] = None
+    #: routed experts the decode program read (per step and MoE layer,
+    #: the held experts a live row was routed to); 0 without experts
+    expert_reads: int = 0
 
 
 @runtime_checkable
@@ -405,7 +408,8 @@ class RealBackend:
                               reprefill_tokens=res.reprefill_tokens,
                               prefill_dur=res.prefill_time,
                               prefix_hit_tokens=res.prefix_hit_tokens,
-                              shared_blocks=res.shared_blocks)
+                              shared_blocks=res.shared_blocks,
+                              expert_reads=res.expert_reads)
 
     def finish_batch(self, wid: int, batch: Batch) -> None:
         if self.kv_retain == "request":

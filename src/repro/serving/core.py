@@ -570,7 +570,8 @@ class SchedulerCore:
                         self._start_static_fcfs(tgt)
         if self.obs.enabled:
             self.obs.on_slice_done(self, wid, b, ex.reprefill_tokens,
-                                   ex.prefix_hit_tokens, ex.shared_blocks)
+                                   ex.prefix_hit_tokens, ex.shared_blocks,
+                                   ex.expert_reads)
         if self.s.mode == "perreq" and w.pending and not w.busy:
             self._start_static_fcfs(w)
         elif self._inline and self.s.mode in ("central", "pred"):
