@@ -13,7 +13,11 @@ Two routed-expert layers share one router (a float32 softmax over all
     capacity-bounded one-hot dispatch/combine einsum moves tokens to
     experts.  Fully static-shaped, with a load-balance aux loss;
   * serving (``moe_serve``) drops no token: the (token, expert) pairs are
-    grouped by expert and run through ``jax.lax.ragged_dot``.
+    grouped by expert and run through ``jax.lax.ragged_dot``.  The served
+    layer scans read the routed experts from the whole (L·n_held, ...)
+    stack by MoE layer index (``expert_stack``), the other layers' groups
+    empty, so a step streams only the experts its tokens hit and copies
+    no layer's experts out of the stack.
 
 Either layer holds only experts ``[expert_offset, expert_offset +
 n_experts_held)`` (expert parallelism, one chip's share): it routes over
@@ -86,8 +90,21 @@ def route(p: Params, x: jnp.ndarray, cfg: ModelConfig
     return w, idx
 
 
+def expert_stack(layers: Params) -> Tuple[Params, Params]:
+    """A MoE layer stack split for the serving layer: (the stack without
+    its routed experts, the experts with the leading (L, n) dims merged
+    into one (L·n, ...) stack).  Merging leading dims is a bitcast; a
+    layer scan that closes over the stack as a loop constant hands the
+    ragged dot the parameter itself, where a scanned (n, d, f) slice
+    would be copied out of the stack for it at every layer and step."""
+    moe_p = dict(layers["moe"])
+    stack = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]),
+                         moe_p.pop("experts"))
+    return dict(layers, moe=moe_p), stack
+
+
 def _moe_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
-               valid: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+               valid: jnp.ndarray, layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``moe_serve`` of one block of tokens x (N,d), valid (N,)."""
     N, d = x.shape
     K, n = cfg.top_k, cfg.experts_held
@@ -101,15 +118,23 @@ def _moe_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     counts = onehot.sum(0)
     rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
     dest = (jnp.cumsum(counts) - counts)[key] + rank
-    tok = jnp.zeros_like(key).at[dest].set(jnp.arange(N * K)) // K
+    # the sorted rows padded to a multiple of 8 (past every group): a TPU
+    # lowers a ragged dot of other row counts to a dense product over
+    # every group of the stack, not to its kernel that skips empty groups
+    M = -(-N * K // 8) * 8
+    tok = jnp.zeros((M,), key.dtype).at[dest].set(jnp.arange(N * K)) // K
     hits = counts[:n]
     e = p["experts"]
+    # this layer's n groups within the whole stack; the others are empty
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((e["gate"]["w"].shape[0],), hits.dtype), hits,
+        (layer * n,))
     xs = x[tok]
-    g = jax.lax.ragged_dot(xs, e["gate"]["w"], hits,
+    g = jax.lax.ragged_dot(xs, e["gate"]["w"], groups,
                            preferred_element_type=x.dtype)
-    u = jax.lax.ragged_dot(xs, e["up"]["w"], hits,
+    u = jax.lax.ragged_dot(xs, e["up"]["w"], groups,
                            preferred_element_type=x.dtype)
-    o = jax.lax.ragged_dot(activate(g, cfg.act) * u, e["down"]["w"], hits,
+    o = jax.lax.ragged_dot(activate(g, cfg.act) * u, e["down"]["w"], groups,
                            preferred_element_type=x.dtype)
     # back to (token, k) order (``dest`` inverts the sort); rows past the
     # last group hold nothing defined: select, not multiply
@@ -121,7 +146,7 @@ def _moe_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
 
 
 def moe_serve(p: Params, x: jnp.ndarray, cfg: ModelConfig,
-              valid: Optional[jnp.ndarray] = None
+              valid: Optional[jnp.ndarray] = None, layer=0
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The serving routed-expert layer: no capacity, no dropped token.
 
@@ -129,28 +154,32 @@ def moe_serve(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     (token, k) pair routed to a held expert is sorted by expert and run
     through the held experts with ``jax.lax.ragged_dot``; the weighted
     outputs are summed back per token, and the shared experts are added
-    once.  More than ``SERVE_BLOCK`` tokens (a long prefill) run a block
-    at a time, which bounds the (token, k) temporaries.  Returns
-    (y (N,d), hits (n_held,) int32: the valid tokens routed to each held
-    expert)."""
+    once.  ``p["experts"]`` is one layer's (n_held, ...) experts, or the
+    whole (L·n_held, ...) stack of ``expert_stack`` with ``layer`` the
+    MoE layer's index in it: the ragged dot then runs on the whole stack
+    with every other layer's groups empty.  More than ``SERVE_BLOCK``
+    tokens (a long prefill) run a block at a time, which bounds the
+    (token, k) temporaries.  Returns (y (N,d), hits (n_held,) int32: the
+    valid tokens routed to each held expert)."""
     N, d = x.shape
     if valid is None:
         valid = jnp.ones((N,), bool)
     if N <= SERVE_BLOCK:
-        return _moe_block(p, x, cfg, valid)
+        return _moe_block(p, x, cfg, valid, layer)
     pad = (-N) % SERVE_BLOCK
     xb = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, SERVE_BLOCK, d)
     vb = jnp.pad(valid, (0, pad)).reshape(-1, SERVE_BLOCK)
-    y, hits = jax.lax.map(lambda a: _moe_block(p, a[0], cfg, a[1]), (xb, vb))
+    y, hits = jax.lax.map(lambda a: _moe_block(p, a[0], cfg, a[1], layer),
+                          (xb, vb))
     return y.reshape(-1, d)[:N], hits.sum(0)
 
 
 def _moe_serve_3d(p: Params, x: jnp.ndarray, cfg: ModelConfig,
-                  valid: Optional[jnp.ndarray] = None):
+                  valid: Optional[jnp.ndarray] = None, layer=0):
     """``moe_serve`` of x (B,T,d), valid (B,T)."""
     B, T, d = x.shape
     y, hits = moe_serve(p, x.reshape(B * T, d), cfg,
-                        None if valid is None else valid.reshape(-1))
+                        None if valid is None else valid.reshape(-1), layer)
     return y.reshape(B, T, d), hits
 
 
@@ -312,8 +341,8 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     # SWA archs only ever need `window` ring slots; full attention needs L_i+S
     Wc = cache_window if window is None else min(cache_window, window)
 
-    def body(moe: bool):
-        def go(carry, layer):
+    def body(experts):
+        def go(carry, layer, l=None):
             x = rms_norm(carry, layer["ln_attn"], cfg.norm_eps)
             if cfg.use_mla:
                 a, c1, c2 = mla.mla_prefill(layer["attn"], x, positions, cfg,
@@ -323,16 +352,20 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                                    cfg, window, Wc, mask=mask)
             h2 = carry + a
             xm = rms_norm(h2, layer["ln_mlp"], cfg.norm_eps)
-            if moe:
-                m, _ = _moe_serve_3d(layer["moe"], xm, cfg)
-            else:
+            if experts is None:
                 m = mlp_apply(layer["mlp"], xm, cfg.act)
+            else:
+                m, _ = _moe_serve_3d(dict(layer["moe"], experts=experts),
+                                     xm, cfg, layer=l)
             return h2 + m, (c1, c2)
         return go
 
     if cfg.first_dense_layers:
-        h, (dk, dv) = scan_layers(body(False), h, params["dense_layers"])
-    h, (k_all, v_all) = scan_layers(body(True), h, params["layers"])
+        h, (dk, dv) = scan_layers(body(None), h, params["dense_layers"])
+    layers, experts = expert_stack(params["layers"])
+    n_moe = cfg.n_layers - cfg.first_dense_layers
+    h, (k_all, v_all) = scan_layers(body(experts), h, layers,
+                                    jnp.arange(n_moe))
     logits = _logits(params, cfg, h[:, -1:])[:, 0]
 
     def mk_cache(k, v):
@@ -359,8 +392,8 @@ def decode_step(params: Params, cfg: ModelConfig, cache: MoECache,
                 else mla.decode_slot_pos(kvc, q_pos))
     h = embed_apply(params["embed"], tokens[:, None], cfg)
 
-    def body(moe: bool):
-        def go(carry, layer, c1, c2):
+    def body(experts):
+        def go(carry, layer, c1, c2, l=None):
             x = rms_norm(carry, layer["ln_attn"], cfg.norm_eps)
             if cfg.use_mla:
                 a, c1, c2 = mla.mla_decode(layer["attn"], x, q_pos, c1, c2,
@@ -370,17 +403,18 @@ def decode_step(params: Params, cfg: ModelConfig, cache: MoECache,
                                                   slot_pos, slot, cfg, window)
             h2 = carry + a
             xm = rms_norm(h2, layer["ln_mlp"], cfg.norm_eps)
-            if moe:
-                m, _ = _moe_serve_3d(layer["moe"], xm, cfg)
-            else:
+            if experts is None:
                 m = mlp_apply(layer["mlp"], xm, cfg.act)
+            else:
+                m, _ = _moe_serve_3d(dict(layer["moe"], experts=experts),
+                                     xm, cfg, layer=l)
             return h2 + m, (c1, c2)
         return go
 
     if cfg.first_dense_layers:
         dc = cache.dense_kv
         d1, d2 = (dc.ckv, dc.kr) if cfg.use_mla else (dc.k, dc.v)
-        h, (nd1, nd2) = scan_layers(body(False), h, params["dense_layers"], d1, d2)
+        h, (nd1, nd2) = scan_layers(body(None), h, params["dense_layers"], d1, d2)
         if cfg.use_mla:
             new_dense = dc._replace(ckv=nd1, kr=nd2, slot_pos=slot_pos,
                                     write_idx=dc.write_idx + 1)
@@ -391,7 +425,10 @@ def decode_step(params: Params, cfg: ModelConfig, cache: MoECache,
         new_dense = cache.dense_kv
 
     c1, c2 = (kvc.ckv, kvc.kr) if cfg.use_mla else (kvc.k, kvc.v)
-    h, (n1, n2) = scan_layers(body(True), h, params["layers"], c1, c2)
+    layers, experts = expert_stack(params["layers"])
+    n_moe = cfg.n_layers - cfg.first_dense_layers
+    h, (n1, n2) = scan_layers(body(experts), h, layers, c1, c2,
+                              jnp.arange(n_moe))
     logits = _logits(params, cfg, h)[:, 0]
     if cfg.use_mla:
         new_kv = kvc._replace(ckv=n1, kr=n2, slot_pos=slot_pos,
@@ -427,9 +464,12 @@ def _paged_stack(params: Params, cfg: ModelConfig, h, k_pages, v_pages,
                  attend, mlp_counts, counts0):
     """The dense layers then the MoE layers over one pool of
     ``n_layers`` layers: ``attend(layer, x, l, kp, vp) -> (a, kp, vp)``
-    is the attention of one layer, ``mlp_counts(hits)`` the counts one
-    MoE layer adds."""
-    def body(moe: bool):
+    is the attention of one layer, ``mlp_counts(p, x, l) -> (m, counts)``
+    the MoE layer ``l`` (counted from the first MoE layer) and the counts
+    it adds; ``p["experts"]`` is the whole stack (``expert_stack``)."""
+    nf = cfg.first_dense_layers
+
+    def body(experts):
         def go(carry, layer, li, kp, vp):
             with jax.named_scope("attention"):
                 a, kp, vp = attend(layer["attn"],
@@ -438,21 +478,22 @@ def _paged_stack(params: Params, cfg: ModelConfig, h, k_pages, v_pages,
             h2 = carry + a
             with jax.named_scope("mlp"):
                 xm = rms_norm(h2, layer["ln_mlp"], cfg.norm_eps)
-                if moe:
-                    m, c = mlp_counts(layer["moe"], xm)
-                else:
+                if experts is None:
                     m, c = mlp_apply(layer["mlp"], xm, cfg.act), counts0
+                else:
+                    m, c = mlp_counts(dict(layer["moe"], experts=experts),
+                                      xm, li - nf)
             return h2 + m, kp, vp, c
         return go
 
     counts = counts0
-    nf = cfg.first_dense_layers
     if nf:
         h, k_pages, v_pages, counts = _scan_paged(
-            body(False), h, params["dense_layers"], 0, k_pages, v_pages,
+            body(None), h, params["dense_layers"], 0, k_pages, v_pages,
             counts)
-    return _scan_paged(body(True), h, params["layers"], nf, k_pages,
-                       v_pages, counts)
+    layers, experts = expert_stack(params["layers"])
+    return _scan_paged(body(experts), h, layers, nf, k_pages, v_pages,
+                       counts)
 
 
 def _prefill_counts(cfg: ModelConfig, positions, block_table):
@@ -460,8 +501,8 @@ def _prefill_counts(cfg: ModelConfig, positions, block_table):
     # nothing (their outputs are dropped)
     valid = (positions >= 0) & (block_table[:, :1] > 0)
 
-    def f(p, xm):
-        m, hits = _moe_serve_3d(p, xm, cfg, valid)
+    def f(p, xm, l):
+        m, hits = _moe_serve_3d(p, xm, cfg, valid, l)
         return m, {"expert_tokens": jnp.sum(hits)}
     return f
 
@@ -543,8 +584,8 @@ def decode_step_paged(params: Params, cfg: ModelConfig, cache,
         return mla.mla_decode_paged(p, x, q_pos, kp, vp, bt, slot_pos, slots,
                                     cfg, li)
 
-    def moe_counts(p, xm):
-        m, hits = _moe_serve_3d(p, xm, cfg, ~done[:, None])
+    def moe_counts(p, xm, l):
+        m, hits = _moe_serve_3d(p, xm, cfg, ~done[:, None], l)
         return m, {"expert_reads": jnp.sum(hits > 0).astype(jnp.int32),
                    "expert_rows": jnp.sum(hits)}
 

@@ -24,7 +24,8 @@ from repro.configs import get_config
 from repro.engine.static_engine import StaticEngine
 from repro.kvcache.paged import PagedKVCache
 from repro.models import attention, mla, moe
-from repro.models.common import yarn_freqs, yarn_rope_mult, yarn_softmax_factor
+from repro.models.common import (stack_layers, yarn_freqs, yarn_rope_mult,
+                                 yarn_softmax_factor)
 from repro.models.registry import get_model
 from repro.obs.trace import Tracer
 
@@ -253,6 +254,97 @@ def test_mixtral_routing_unchanged():
     np.testing.assert_allclose(np.asarray(train).reshape(-1, cfg.d_model),
                                np.asarray(want), atol=1e-5)
     np.testing.assert_allclose(np.asarray(serve), np.asarray(want), atol=1e-5)
+
+
+def _moe_stack(n_layers=4, seed=5):
+    """A stack of ``n_layers`` MoE layers in bf16, as served, whose
+    router, for an input whose first feature is 1, always picks held
+    expert 0 (logit +5) and never held expert 3 (logit -30)."""
+    cfg = CFG.replace(dtype=jnp.bfloat16)
+    stacked = stack_layers(lambda k: moe.init_moe_layer(k, cfg),
+                           jax.random.PRNGKey(seed), n_layers)
+    w = stacked["router"]["w"]
+    for e, logit in ((0, 5.0), (3, -30.0)):
+        e += cfg.expert_offset
+        w = w.at[:, :, e].set(0.0).at[:, 0, e].set(logit)
+    stacked["router"]["w"] = w
+    return cfg, stacked
+
+
+@pytest.mark.parametrize("n_tokens", [1, 4, 16, moe.SERVE_BLOCK + 9])
+@pytest.mark.parametrize("layer", [0, 2, 3])
+def test_whole_stack_layer_matches_per_layer(layer, n_tokens):
+    """The serving layer on the whole (L·n, ...) expert stack at MoE
+    layer ``layer`` (first, middle, last) gives the per-layer layer's
+    output on that layer's (n, ...) slice, to bf16 rounding, and the
+    same hits: decode-sized inputs with some rows done, and a prefill
+    past one ``SERVE_BLOCK``; held expert 3 is never routed to, so its
+    group is empty in both."""
+    cfg, stacked = _moe_stack()
+    x = jax.random.normal(jax.random.fold_in(KEY, n_tokens),
+                          (n_tokens, cfg.d_model)).at[:, 0].set(1.0)
+    x = x.astype(cfg.dtype)
+    valid = jnp.arange(n_tokens) % 3 != 2  # some rows done
+    one = jax.tree_util.tree_map(lambda a: a[layer], stacked)
+    want, want_hits = moe.moe_serve(one, x, cfg, valid)
+    rest, experts = moe.expert_stack({"moe": stacked})
+    p = dict(jax.tree_util.tree_map(lambda a: a[layer], rest["moe"]),
+             experts=experts)
+    assert experts["gate"]["w"].shape[0] == 4 * cfg.experts_held
+    got, hits = jax.jit(moe.moe_serve, static_argnums=2)(p, x, cfg, valid,
+                                                        layer)
+    np.testing.assert_array_equal(np.asarray(hits), np.asarray(want_hits))
+    assert int(hits[3]) == 0
+    assert int(hits.sum()) > 0
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2 ** -7, atol=2 ** -7)
+
+
+def _eqns(jaxpr, name):
+    """Every ``name`` equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, name)
+
+
+def test_decode_scan_closes_over_the_expert_stack(setup):
+    """The paged decode step's MoE layer scan takes the routed experts
+    as loop constants, the whole (L·n, ...) stack, and not as scanned
+    (L, n, ...) inputs that each layer would slice and copy; each ragged
+    dot runs on that stack, its rows (one row's top_k = 3 pairs here)
+    padded to a multiple of 8, which a TPU runs with its kernel."""
+    model, params = setup
+    pg, nb = 16, 2
+    kp, vp = _pools(model, nb, pg)
+    cache = PagedKVCache(kp, vp, jnp.arange(1, nb + 1, dtype=jnp.int32)[None],
+                         jnp.full((1, nb * pg), -1, jnp.int32),
+                         jnp.zeros((1,), jnp.int32))
+    q = jnp.zeros((1,), jnp.int32)
+    jaxpr = jax.make_jaxpr(model.decode_step_paged)(
+        params, cache, q, q, q, jnp.zeros((1,), bool)).jaxpr
+    layer_shapes = {a.shape for a in jax.tree_util.tree_leaves(
+        params["layers"]["moe"]["experts"])}
+    stack_shapes = {(s[0] * s[1],) + s[2:] for s in layer_shapes}
+    n_moe = CFG.n_layers - CFG.first_dense_layers
+    scans = [e for e in _eqns(jaxpr, "scan") if e.params["length"] == n_moe]
+    assert scans
+    for eqn in scans:
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        consts = {v.aval.shape for v in eqn.invars[:nc]}
+        xs = {v.aval.shape for v in eqn.invars[nc + nk:]}
+        assert stack_shapes <= consts
+        assert not layer_shapes & xs
+    dots = list(_eqns(jaxpr, "ragged_dot_general"))
+    assert len(dots) == 3
+    for eqn in dots:
+        lhs, rhs = (v.aval.shape for v in eqn.invars[:2])
+        assert rhs in stack_shapes and lhs[0] == 8
 
 
 def _reference_routes(params, seq):
